@@ -17,6 +17,7 @@ from __future__ import annotations
 import argparse
 import statistics
 import time
+import traceback
 from typing import Callable, List, Tuple
 
 ROWS: List[Tuple[str, float, str]] = []
@@ -210,8 +211,10 @@ def bench_train_step(quick: bool) -> None:
         jax.block_until_ready(m["loss"])
 
     us = timeit(one, 3 if quick else 5)
-    record("train_step_10m_cpu", us,
-           f"{n_tokens} tok/step; {n_tokens/(us/1e6):.0f} tok/s (1 CPU core)")
+    dev = jax.devices()[0]
+    record("train_step_10m", us,
+           f"{n_tokens} tok/step; {n_tokens/(us/1e6):.0f} tok/s on {dev.platform} "
+           f"{dev.device_kind} x{len(jax.devices())}")
 
 
 BENCHES = [bench_setup_overhead, bench_gateway_scheduling,
@@ -226,13 +229,15 @@ def main() -> None:
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
     print("name,us_per_call,derived")
+    failed = []
     for bench in BENCHES:
         if args.only and args.only not in bench.__name__:
             continue
         try:
             bench(args.quick)
-        except Exception as exc:  # pragma: no cover
-            record(bench.__name__ + "_ERROR", -1, str(exc)[:100])
+        except Exception:
+            traceback.print_exc()
+            failed.append(bench.__name__)
     import csv
     import os
 
@@ -241,6 +246,8 @@ def main() -> None:
         w = csv.writer(fh)
         w.writerow(["name", "us_per_call", "derived"])
         w.writerows(ROWS)
+    if failed:
+        raise SystemExit(f"benchmarks failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

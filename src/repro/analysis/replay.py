@@ -211,6 +211,9 @@ def _canonical_root_obj(obj: Any) -> Optional[str]:
     if qualname is None:
         return None
     module = getattr(obj, "__module__", None)
+    if module == "_io":
+        # CPython's C implementation of ``io``: builtin ``open`` reports it
+        module = "io"
     full = qualname if module in (None, "builtins") else f"{module}.{qualname}"
     # numpy's legacy global RNG surface lives on a hidden RandomState
     # singleton in numpy.random.mtrand — normalize to the public path

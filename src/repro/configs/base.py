@@ -86,7 +86,7 @@ class ModelConfig:
     remat: str = "full"              # none | full | dots  (activation ckpt policy)
     z_loss_coef: float = 1e-4
 
-    # attention impl selector (ops.py): auto | ref | pallas | dense
+    # kernel impl selector (kernels/ops.py): auto | ref | pallas | interpret | dense
     attn_impl: str = "auto"
 
     def __post_init__(self):

@@ -26,9 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax<0.5 names it TPUCompilerParams; the kwargs are the same either way
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 from . import ref as _ref
 
 __all__ = ["flash_attention_pallas"]
@@ -114,7 +111,7 @@ def _fwd_impl(q, k, v, *, causal, window, scale, block_q, block_k, interpret):
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, Dv), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -3,29 +3,83 @@
 Models import ONLY from this module. The dispatch decision is made once per
 call site from the default backend (or forced via ``impl=``):
 
-  impl="auto"    : pallas on TPU, blocked-jnp reference elsewhere
-  impl="pallas"  : force the Pallas kernel (interpret=True off-TPU — tests)
-  impl="ref"     : force the blocked reference
-  impl="dense"   : O(S²) dense oracle (tiny test shapes only)
+  impl="auto"      : pallas on TPU, blocked-jnp reference elsewhere
+  impl="pallas"    : the compiled Pallas (Mosaic) kernel; TPU backend only
+  impl="interpret" : the same kernel run by the Pallas interpreter (tests)
+  impl="ref"       : force the blocked reference
+  impl="dense"     : O(S²) dense oracle (tiny test shapes only)
 """
 from __future__ import annotations
 
+import math
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from . import ref as _ref
 
 __all__ = ["flash_attention", "wkv6", "rglru", "default_impl"]
 
+_IMPLS = ("auto", "pallas", "interpret", "ref", "dense")
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
 
 def default_impl() -> str:
-    return "pallas" if jax.default_backend() == "tpu" else "ref"
+    return "pallas" if _on_tpu() else "ref"
 
 
 def _resolve(impl: str) -> str:
-    return default_impl() if impl == "auto" else impl
+    if impl not in _IMPLS:
+        raise ValueError(f"unknown kernel impl {impl!r}; expected one of {_IMPLS}")
+    if impl == "auto":
+        return default_impl()
+    if impl == "pallas" and not _on_tpu():
+        raise RuntimeError(
+            f"impl='pallas' compiles a Mosaic kernel, which needs the TPU "
+            f"backend (default backend: {jax.default_backend()!r}); pass "
+            f"impl='interpret' to run the kernel in the Pallas interpreter")
+    return impl
+
+
+def _per_shard(kernel, args, in_dims, out_dims):
+    """Call a Mosaic kernel once per shard of the installed mesh.
+
+    XLA cannot partition a Mosaic call, so under a multi-device mesh (see
+    ``repro.models.layers.set_mesh_context``) the kernel runs inside
+    ``shard_map``. ``kernel`` returns a tuple. ``in_dims`` maps each
+    argument, and ``out_dims`` each ``(ndim, dims)`` output, to its batch
+    dim ``"b"`` (split over the data axes) and its head/width dim ``"m"``
+    (split over the model axis). An axis that does not divide every such
+    dim is not split.
+    """
+    from repro.models.layers import get_mesh_context
+
+    ctx = get_mesh_context()
+    if ctx is None or ctx["mesh"].size == 1:
+        return kernel(*args)
+    mesh = ctx["mesh"]
+    axes = {"b": tuple(ctx["dp_axes"]) or None, "m": ctx["model_axis"]}
+    for key, ax in list(axes.items()):
+        size = math.prod(mesh.shape[a] for a in ((ax,) if isinstance(ax, str) else ax or ()))
+        if size == 1 or any(x.shape[d[key]] % size
+                            for x, d in zip(args, in_dims, strict=True) if key in d):
+            axes[key] = None
+
+    def spec(ndim, dims):
+        parts = [None] * ndim
+        for key, i in dims.items():
+            parts[i] = axes[key]
+        return P(*parts)
+
+    in_specs = tuple(spec(x.ndim, d) for x, d in zip(args, in_dims, strict=True))
+    out_specs = tuple(spec(n, d) for n, d in out_dims)
+    return jax.shard_map(kernel, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)(*args)
 
 
 # --------------------------------------------------------------------------
@@ -45,10 +99,13 @@ def flash_attention(q, k, v, *, causal: bool = True, window: Optional[int] = Non
                                         scale=scale)
     from .flash_attention import flash_attention_pallas
 
-    interpret = jax.default_backend() != "tpu"
-    return flash_attention_pallas(q, k, v, causal=causal, window=window, scale=scale,
-                                  block_q=block_q, block_k=block_k,
-                                  interpret=interpret)
+    def kernel(q, k, v):
+        return (flash_attention_pallas(q, k, v, causal=causal, window=window, scale=scale,
+                                       block_q=block_q, block_k=block_k,
+                                       interpret=impl == "interpret"),)
+
+    bh = {"b": 0, "m": 1}
+    return _per_shard(kernel, (q, k, v), (bh, bh, bh), ((4, bh),))[0]
 
 
 # --------------------------------------------------------------------------
@@ -81,9 +138,15 @@ def wkv6(r, k, v, w, u, *, initial_state=None, chunk: int = 16,
     else:
         from .rwkv6 import wkv6_pallas
 
-        interpret = jax.default_backend() != "tpu"
-        out, state = wkv6_pallas(r, k, v, w, u, initial_state=initial_state,
-                                 chunk=chunk, interpret=interpret)
+        def kernel(r, k, v, w, u, *s0):
+            return wkv6_pallas(r, k, v, w, u, initial_state=s0[0] if s0 else None,
+                               chunk=chunk, interpret=impl == "interpret")
+
+        s0 = () if initial_state is None else (initial_state,)
+        bh = {"b": 0, "m": 1}
+        out, state = _per_shard(kernel, (r, k, v, w, u, *s0),
+                                (bh, bh, bh, bh, {"m": 0}) + (bh,) * len(s0),
+                                ((4, bh), (4, bh)))
     if pad:
         out = out[:, :, :T, :]
     return out, state
@@ -94,7 +157,7 @@ def wkv6(r, k, v, w, u, *, initial_state=None, chunk: int = 16,
 # --------------------------------------------------------------------------
 
 def rglru(x, a, *, initial_state=None, impl: str = "auto",
-          chunk: int = 256) -> Tuple[jnp.ndarray, jnp.ndarray]:
+          chunk: int = 256, block_w: int = 512) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """RG-LRU diagonal recurrence. x,a:(B,T,D) → (h (B,T,D), state (B,D))."""
     impl = _resolve(impl)
     if impl == "dense":
@@ -103,6 +166,11 @@ def rglru(x, a, *, initial_state=None, impl: str = "auto",
         return _ref.rglru_scan_ref(x, a, initial_state=initial_state)
     from .rglru import rglru_pallas
 
-    interpret = jax.default_backend() != "tpu"
-    return rglru_pallas(x, a, initial_state=initial_state, chunk=chunk,
-                        interpret=interpret)
+    def kernel(x, a, *h0):
+        return rglru_pallas(x, a, initial_state=h0[0] if h0 else None, chunk=chunk,
+                            block_w=block_w, interpret=impl == "interpret")
+
+    h0 = () if initial_state is None else (initial_state,)
+    btw, bw = {"b": 0, "m": 2}, {"b": 0, "m": 1}
+    return _per_shard(kernel, (x, a, *h0), (btw, btw) + (bw,) * len(h0),
+                      ((3, btw), (2, bw)))

@@ -9,6 +9,10 @@ is bandwidth-bound by design. Layout:
     of h per element, the bandwidth floor.
   - channel blocks bw = 512 lanes keep the VPU vectorized; within a chunk a
     fori_loop steps the recurrence (chunk × elementwise ops, no HBM traffic).
+  - Mosaic layout rules shape the body: the loop reads a_t and the gated
+    input row by row from f32 VMEM scratch and writes h_t into a third one
+    (``pl.ds(t, 1)`` on refs; values cannot be sliced dynamically), and the
+    state travels as (B, 1, W) so its block is a whole (1, bw) tile.
 """
 from __future__ import annotations
 
@@ -20,39 +24,34 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax<0.5 names it TPUCompilerParams; the kwargs are the same either way
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 __all__ = ["rglru_pallas"]
 
 
-def _rglru_kernel(x_ref, a_ref, h0_ref, h_ref, hT_ref, h_scr, *,
-                  chunk: int, nt: int):
+def _rglru_kernel(x_ref, a_ref, h0_ref, h_ref, hT_ref,
+                  h_scr, a_scr, g_scr, o_scr, *, chunk: int, nt: int):
     it = pl.program_id(2)
 
     @pl.when(it == 0)
     def _init():
-        h_scr[...] = h0_ref[0].astype(jnp.float32)[None, :]
+        h_scr[...] = h0_ref[0].astype(jnp.float32)
 
-    x = x_ref[0].astype(jnp.float32)             # (chunk, bw)
     a = a_ref[0].astype(jnp.float32)             # (chunk, bw)
-    gated = jnp.sqrt(jnp.maximum(1.0 - a * a, 0.0)) * x
+    a_scr[...] = a
+    g_scr[...] = jnp.sqrt(jnp.maximum(1.0 - a * a, 0.0)) * x_ref[0].astype(jnp.float32)
 
-    def step(t, carry):
-        h, out = carry
-        h = a[t][None, :] * h + gated[t][None, :]
-        out = jax.lax.dynamic_update_slice(out, h, (t, 0))
-        return h, out
+    def step(t, h):
+        row = pl.ds(t, 1)
+        h = a_scr[row, :] * h + g_scr[row, :]     # (1, bw)
+        o_scr[row, :] = h
+        return h
 
-    h0 = h_scr[...]                               # (1, bw)
-    out0 = jnp.zeros((chunk, x.shape[1]), jnp.float32)
-    h_last, outs = jax.lax.fori_loop(0, chunk, step, (h0, out0))
-    h_ref[0] = outs.astype(h_ref.dtype)
+    h_last = jax.lax.fori_loop(0, chunk, step, h_scr[...])
+    h_ref[0] = o_scr[...].astype(h_ref.dtype)
     h_scr[...] = h_last
 
     @pl.when(it == nt - 1)
     def _write_state():
-        hT_ref[0] = h_last[0]
+        hT_ref[0] = h_last
 
 
 def rglru_pallas(x, a, *, initial_state=None, chunk: int = 256,
@@ -72,8 +71,7 @@ def rglru_pallas(x, a, *, initial_state=None, chunk: int = 256,
     Wp, Tp = x.shape[2], x.shape[1]
     nw, nt = Wp // bw, Tp // chunk
     h0 = (jnp.zeros((B, Wp), jnp.float32) if initial_state is None
-          else jnp.pad(initial_state.astype(jnp.float32), ((0, 0), (0, padw)))
-          if padw else initial_state.astype(jnp.float32))
+          else jnp.pad(initial_state.astype(jnp.float32), ((0, 0), (0, padw))))
 
     kernel = functools.partial(_rglru_kernel, chunk=chunk, nt=nt)
     h, hT = pl.pallas_call(
@@ -82,19 +80,22 @@ def rglru_pallas(x, a, *, initial_state=None, chunk: int = 256,
         in_specs=[
             pl.BlockSpec((1, chunk, bw), lambda b, iw, it: (b, it, iw)),
             pl.BlockSpec((1, chunk, bw), lambda b, iw, it: (b, it, iw)),
-            pl.BlockSpec((1, bw), lambda b, iw, it: (b, iw)),
+            pl.BlockSpec((1, 1, bw), lambda b, iw, it: (b, 0, iw)),
         ],
         out_specs=[
             pl.BlockSpec((1, chunk, bw), lambda b, iw, it: (b, it, iw)),
-            pl.BlockSpec((1, bw), lambda b, iw, it: (b, iw)),
+            pl.BlockSpec((1, 1, bw), lambda b, iw, it: (b, 0, iw)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, Tp, Wp), x.dtype),
-            jax.ShapeDtypeStruct((B, Wp), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, Wp), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((1, bw), jnp.float32)],
-        compiler_params=_CompilerParams(
+        scratch_shapes=[pltpu.VMEM((1, bw), jnp.float32),
+                        pltpu.VMEM((chunk, bw), jnp.float32),
+                        pltpu.VMEM((chunk, bw), jnp.float32),
+                        pltpu.VMEM((chunk, bw), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(x, a, h0)
-    return h[:, :T, :W], hT[:, :W]
+    )(x, a, h0[:, None, :])
+    return h[:, :T, :W], hT[:, 0, :W]

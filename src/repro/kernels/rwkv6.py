@@ -12,6 +12,11 @@ TPU adaptation of the (inherently sequential) WKV scan:
     but batched across the (B, H) parallel grid dims, which is where v5e's
     8 parallel sublanes earn their keep; the win over a per-step scan is
     ~chunk× fewer sequential dependencies.
+  - Mosaic layout rules shape the body: ``u`` arrives as (H, 1, K) so its
+    block is a whole (1, K) tile; the in-chunk prefix sum of log w is a
+    matmul with a lower-triangular ones matrix (Mosaic has no cumsum); the
+    per-key decay column that scales S is a lane reduction of a diagonal
+    (no (K,) → (K, 1) relayout).
 """
 from __future__ import annotations
 
@@ -23,10 +28,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax<0.5 names it TPUCompilerParams; the kwargs are the same either way
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 __all__ = ["wkv6_pallas"]
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _mm(a, b, contract):
+    """f32 matmul at full precision: exp() of a prefix sum amplifies error."""
+    return jax.lax.dot_general(a, b, (contract, ((), ())), precision=_HIGHEST,
+                               preferred_element_type=jnp.float32)
 
 
 def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
@@ -41,31 +51,32 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
     k = k_ref[0, 0].astype(jnp.float32)          # (c, K)
     v = v_ref[0, 0].astype(jnp.float32)          # (c, V)
     w = w_ref[0, 0].astype(jnp.float32)          # (c, K)
-    u = u_ref[0].astype(jnp.float32)             # (K,)
+    u = u_ref[0].astype(jnp.float32)             # (1, K)
     S = S_scr[...]                                # (K, V)
+    K = S.shape[0]
 
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     logw = jnp.log(jnp.maximum(w, 1e-38))
-    cum = jnp.cumsum(logw, axis=0)               # (c, K)
+    cum = _mm(jnp.where(col <= row, 1.0, 0.0), logw, ((1,), (0,)))   # (c, K)
+    cum_last = cum[chunk - 1:chunk, :]            # (1, K)
     Dt = jnp.exp(cum)
     Dt_prev = jnp.exp(cum - logw)
     r_hat = r * Dt_prev
     k_hat = k / jnp.maximum(Dt, 1e-30)
 
-    cross = jax.lax.dot_general(r_hat, S, (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)   # (c, V)
-    att = jax.lax.dot_general(r_hat, k_hat, (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.float32)     # (c, c)
-    tri = jnp.tril(jnp.ones((chunk, chunk), jnp.float32), -1)
-    intra = jax.lax.dot_general(att * tri, v, (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-    diag = ((r * u[None, :]) * k).sum(axis=1, keepdims=True) * v
+    cross = _mm(r_hat, S, ((1,), (0,)))                              # (c, V)
+    att = _mm(r_hat, k_hat, ((1,), (1,)))                            # (c, c)
+    intra = _mm(jnp.where(col < row, att, 0.0), v, ((1,), (0,)))     # (c, V)
+    diag = ((r * u) * k).sum(axis=1, keepdims=True) * v
     o_ref[0, 0] = (cross + intra + diag).astype(o_ref.dtype)
 
-    D_last = Dt[-1, :]                            # (K,)
-    k_scaled = k * jnp.exp(cum[-1:, :] - cum)     # (c, K)
-    S_new = D_last[:, None] * S + jax.lax.dot_general(
-        k_scaled, v, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)       # (K, V)
+    # S ← diag(D_last) S + Σ_t (k_t ⊙ D_last / D_t) v_tᵀ
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (K, K), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (K, K), 1))
+    d_col = jnp.where(eye, jnp.exp(cum_last), 0.0).sum(axis=1, keepdims=True)  # (K, 1)
+    k_scaled = k * jnp.exp(cum_last - cum)        # (c, K)
+    S_new = d_col * S + _mm(k_scaled, v, ((0,), (0,)))                # (K, V)
     S_scr[...] = S_new
 
     @pl.when(it == nt - 1)
@@ -92,7 +103,7 @@ def wkv6_pallas(r, k, v, w, u, *, initial_state=None, chunk: int = 16,
             pl.BlockSpec((1, 1, chunk, K), lambda b, h, t: (b, h, t, 0)),
             pl.BlockSpec((1, 1, chunk, V), lambda b, h, t: (b, h, t, 0)),
             pl.BlockSpec((1, 1, chunk, K), lambda b, h, t: (b, h, t, 0)),
-            pl.BlockSpec((1, K), lambda b, h, t: (h, 0)),
+            pl.BlockSpec((1, 1, K), lambda b, h, t: (h, 0, 0)),
             pl.BlockSpec((1, 1, K, V), lambda b, h, t: (b, h, 0, 0)),
         ],
         out_specs=[
@@ -104,8 +115,8 @@ def wkv6_pallas(r, k, v, w, u, *, initial_state=None, chunk: int = 16,
             jax.ShapeDtypeStruct((B, H, K, V), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((K, V), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(r, k, v, w, u, s0)
+    )(r, k, v, w, u.reshape(H, 1, K), s0)
     return out, sT

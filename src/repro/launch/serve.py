@@ -17,6 +17,7 @@ import numpy as np
 from repro.configs import get_config, list_archs, smoke_variant
 from repro.core import (Context, Gateway, TaskRegistry, WorkerClient,
                         WorkerServer)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build
 
 
@@ -47,6 +48,7 @@ def build_registry(cfg, model, params) -> TaskRegistry:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-1.7b", choices=list(list_archs()))
     ap.add_argument("--workers", type=int, default=2)

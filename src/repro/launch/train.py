@@ -10,11 +10,13 @@ from __future__ import annotations
 import argparse
 
 from repro.configs import SHAPES, get_config, list_archs, smoke_variant
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim.adamw import AdamWConfig
 from repro.train.trainer import TrainConfig, Trainer
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=list(list_archs()))
     ap.add_argument("--shape", default="train_4k", choices=list(SHAPES))

@@ -52,7 +52,7 @@ class ShardingRules:
         self.model_size = mesh.shape["model"] if self.model_axis else 1
         dp: Axis = self.dp_axes if len(self.dp_axes) > 1 else \
             (self.dp_axes[0] if self.dp_axes else None)
-        fsdp_axis: Axis = dp if options.fsdp else None
+        fsdp_axis: Axis = dp if self.opt.fsdp else None
         self.table: Dict[str, Axis] = {
             "layers": None,
             "vocab": self.model_axis,
@@ -61,18 +61,18 @@ class ShardingRules:
             "kv_heads": self.model_axis,
             "mlp": self.model_axis,
             "moe_mlp": self.model_axis,
-            "experts": self.model_axis if options.expert_parallel else fsdp_axis,
+            "experts": self.model_axis if self.opt.expert_parallel else fsdp_axis,
             "lru": self.model_axis,
             "lora": None,
         }
-        for k, v in options.logical_overrides:
+        for k, v in self.opt.logical_overrides:
             self.table[k] = v
         self.dp: Axis = dp
 
         kv = max(cfg.num_kv_heads, 1)
-        if options.cache_seq_shard == "heads":
+        if self.opt.cache_seq_shard == "heads":
             self.cache_on_heads = True
-        elif options.cache_seq_shard == "seq":
+        elif self.opt.cache_seq_shard == "seq":
             self.cache_on_heads = False
         else:
             self.cache_on_heads = (kv % max(self.model_size, 1) == 0

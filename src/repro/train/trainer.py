@@ -49,6 +49,7 @@ from repro.core import (
 from repro.obs.metrics import metrics as obs_metrics
 from repro.wire import canonical_digest, payload_digest
 from repro.data.pipeline import DataConfig, TokenSource
+from repro.launch.mesh import make_mesh
 from repro.models import build
 from repro.optim.adamw import AdamWConfig
 from repro.sharding.specs import ShardingOptions, ShardingRules
@@ -97,7 +98,7 @@ class Trainer:
         # elastic mesh: data axis = current device count / model axis
         n = len(jax.devices())
         model_ax = min(tc.mesh_model_axis, n)
-        self.mesh = jax.make_mesh((max(1, n // model_ax), model_ax), ("data", "model"))
+        self.mesh = make_mesh((max(1, n // model_ax), model_ax), ("data", "model"))
         self.rules = ShardingRules(cfg, self.mesh, ShardingOptions())
         # The fresh-execution step donates params/opt buffers (in-place
         # update memory profile). The VERIFY twin does not: a replayed step
@@ -124,6 +125,11 @@ class Trainer:
             },
             origin="trainer",
         )
+
+    def device_batch(self, step: int) -> Dict[str, jax.Array]:
+        """Step ``step``'s batch on the mesh, split along ``data``."""
+        batch = self.source.batch_at(step)
+        return jax.device_put(batch, self.rules.batch_spec(batch))
 
     # -- recovery ------------------------------------------------------------
     def recover(self) -> Tuple[int, Any, Any]:
@@ -190,8 +196,7 @@ class Trainer:
 
             def run_step(ctx, _s=s, _fid=fetch_id, **deps):
                 meta = deps[_fid]
-                batch = self.source.batch_at(_s)  # DI: regenerate (pure fn)
-                jbatch = {k: jnp.asarray(v) for k, v in batch.items()}
+                jbatch = self.device_batch(_s)  # DI: regenerate (pure fn)
                 want = replay_digests.get(_s)
                 if _s in self._donated_steps:
                     # the donating step already consumed this state's device

@@ -1,4 +1,4 @@
-"""Pallas kernels vs pure-jnp oracles (interpret=True on CPU).
+"""Pallas kernels vs pure-jnp oracles (explicit interpret mode on CPU).
 
 Sweeps shapes/dtypes per kernel; asserts allclose against ref.py.
 """
@@ -10,8 +10,6 @@ from _propcheck import given, settings, st
 
 from repro.kernels import ops, ref
 from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.rglru import rglru_pallas
-from repro.kernels.rwkv6 import wkv6_pallas
 
 RNG = np.random.default_rng(7)
 
@@ -122,8 +120,8 @@ def test_wkv6_kernel_matches_sequential_oracle(case):
     w = decays((B, H, T, K))
     u = rand((H, K))
     s0 = rand((B, H, K, V)) if with_state else None
-    out, sT = wkv6_pallas(r, k, v, w, u, initial_state=s0, chunk=chunk,
-                          interpret=True)
+    out, sT = ops.wkv6(r, k, v, w, u, initial_state=s0, chunk=chunk,
+                       impl="interpret")
     want, sT_want = ref.wkv6_ref(r, k, v, w, u, initial_state=s0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
@@ -138,7 +136,7 @@ def test_wkv6_ops_pads_ragged_T():
     v = rand((B, H, T, K))
     w = decays((B, H, T, K))
     u = rand((H, K))
-    out, sT = ops.wkv6(r, k, v, w, u, impl="pallas")
+    out, sT = ops.wkv6(r, k, v, w, u, impl="interpret")
     want, sT_want = ref.wkv6_ref(r, k, v, w, u)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
@@ -179,8 +177,8 @@ def test_rglru_kernel_matches_sequential_oracle(case):
     x = rand((B, T, W))
     a = jnp.asarray(1 / (1 + np.exp(-RNG.normal(size=(B, T, W)))), jnp.float32)
     h0 = rand((B, W)) if with_state else None
-    h, hT = rglru_pallas(x, a, initial_state=h0, chunk=chunk, block_w=64,
-                         interpret=True)
+    h, hT = ops.rglru(x, a, initial_state=h0, chunk=chunk, block_w=64,
+                      impl="interpret")
     want, hT_want = ref.rglru_ref(x, a, initial_state=h0)
     np.testing.assert_allclose(np.asarray(h), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
@@ -200,6 +198,90 @@ def test_rglru_state_chaining_equals_one_shot():
                                np.asarray(full), rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(np.asarray(s2), np.asarray(s_full),
                                rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("op", ["flash_attention", "wkv6", "rglru"])
+def test_pallas_impl_refuses_non_tpu_backend(op, monkeypatch):
+    """impl="pallas" means the compiled Mosaic kernel, never a silent
+    fallback to the interpreter; off-TPU it must raise."""
+    x = rand((1, 2, 16, 16))
+    args = {"flash_attention": (x, x, x),
+            "wkv6": (x, x, x, decays(x.shape), rand((2, 16))),
+            "rglru": (x[0], decays(x[0].shape))}[op]
+    monkeypatch.setattr(ops, "_on_tpu", lambda: False)
+    with pytest.raises(RuntimeError, match="impl='interpret'"):
+        getattr(ops, op)(*args, impl="pallas")
+
+
+def _op_args(op):
+    if op == "flash_attention":
+        return (rand((2, 4, 64, 32)), rand((2, 2, 64, 32)), rand((2, 2, 64, 32))), {}
+    if op == "wkv6":
+        shape = (2, 4, 32, 16)
+        return (rand(shape), rand(shape), rand(shape), decays(shape), rand((4, 16))), {
+            "initial_state": rand((2, 4, 16, 16))}
+    x = rand((2, 40, 32))
+    a = jnp.asarray(1 / (1 + np.exp(-RNG.normal(size=x.shape))), jnp.float32)
+    return (x, a), {"initial_state": rand((2, 32)), "chunk": 16, "block_w": 16}
+
+
+@pytest.mark.parametrize("op", ["flash_attention", "wkv6", "rglru"])
+def test_ops_interpret_matches_ref(op):
+    """The ops-level dispatch (argument plumbing, padding) around each kernel."""
+    args, kw = _op_args(op)
+    got = jax.tree.leaves(getattr(ops, op)(*args, impl="interpret", **kw))
+    want = jax.tree.leaves(getattr(ops, op)(*args, impl="ref", **kw))
+    for g, w in zip(got, want, strict=True):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-4, atol=2e-4)
+
+
+_PER_SHARD_SCRIPT = """
+import jax, numpy as np
+from repro.kernels import ops
+from repro.launch.mesh import make_mesh
+from repro.models.layers import set_mesh_context
+import test_kernels as tk
+
+mesh = make_mesh((2, 2), ("data", "model"))
+for op in ("flash_attention", "wkv6", "rglru"):
+    args, kw = tk._op_args(op)
+    want = jax.tree.leaves(getattr(ops, op)(*args, impl="ref", **kw))
+    set_mesh_context({"mesh": mesh, "dp_axes": ("data",), "model_axis": "model"})
+    f = jax.jit(lambda *a, op=op, kw=kw: getattr(ops, op)(*a, impl="interpret", **kw))
+    text = f.lower(*args).as_text()
+    got = jax.tree.leaves(f(*args))
+    set_mesh_context(None)
+    assert "manual_computation" in text, op
+    for g, w in zip(got, want, strict=True):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-4, atol=2e-4)
+print("per-shard ok")
+"""
+
+
+def test_kernels_run_per_shard_under_a_multi_device_mesh(tmp_path):
+    """Mosaic calls cannot be partitioned by XLA: under a 2x2 mesh each op
+    runs its kernel inside shard_map (batch on data, heads/width on model)
+    and still matches the reference. Four host devices need their own
+    process, since the device count is fixed when JAX starts."""
+    import os
+    import subprocess
+    import sys
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.pathsep.join(
+                   [os.path.join(here, "..", "src"), here, os.environ.get("PYTHONPATH", "")]))
+    out = subprocess.run([sys.executable, "-c", _PER_SHARD_SCRIPT], env=env, cwd=tmp_path,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-4000:]
+    assert "per-shard ok" in out.stdout
+
+
+def test_unknown_impl_is_rejected():
+    x = rand((1, 2, 16, 16))
+    with pytest.raises(ValueError, match="unknown kernel impl"):
+        ops.flash_attention(x, x, x, impl="mosaic")
 
 
 def test_ref_blocked_equals_dense_large_window_cases():

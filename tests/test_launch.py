@@ -150,3 +150,55 @@ def test_smoke_variants_all_small():
         assert sm.num_layers <= 4 and sm.d_model <= 128
         assert sm.family == cfg.family
         assert sm.param_count() < 5e6 or sm.vocab_size <= 512
+
+
+# ---------------------------------------------------------------------------
+# persistent compilation cache placement
+# ---------------------------------------------------------------------------
+def _recorded_updates(monkeypatch):
+    import jax
+
+    calls = []
+    monkeypatch.setattr(jax.config, "update", lambda k, v: calls.append((k, v)))
+    return calls
+
+
+def test_compile_cache_defers_to_env(monkeypatch, tmp_path):
+    from repro.launch.compile_cache import enable_compile_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    calls = _recorded_updates(monkeypatch)
+    assert enable_compile_cache() == str(tmp_path)
+    assert calls == []  # JAX reads the variable itself; no path is set in code
+
+
+def test_compile_cache_fixed_path_inside_checkout(monkeypatch, tmp_path):
+    from pathlib import Path
+
+    import repro
+    from repro.launch import compile_cache
+
+    checkout = Path(repro.__file__).resolve().parents[2]
+    assert compile_cache.CACHE_DIR == checkout / ".jax_cache"
+    assert ".jax_cache/" in (checkout / ".gitignore").read_text().split()
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(compile_cache, "CACHE_DIR", tmp_path / "cache")
+    calls = _recorded_updates(monkeypatch)
+    assert compile_cache.enable_compile_cache() == str(tmp_path / "cache")
+    assert calls == [("jax_compilation_cache_dir", str(tmp_path / "cache"))]
+    assert (tmp_path / "cache").is_dir()
+
+
+def test_importing_repro_sets_no_compile_cache():
+    import subprocess
+    import sys
+
+    code = (
+        "import jax, repro, repro.launch.compile_cache, repro.train.trainer\n"
+        "assert jax.config.jax_compilation_cache_dir is None, jax.config.jax_compilation_cache_dir\n"
+    )
+    env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "src")
+    out = subprocess.run([sys.executable, "-c", code], env=env, capture_output=True,
+                         text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
