@@ -33,6 +33,7 @@ from typing import Any, Dict, Optional
 from repro.obs.trace import get_tracer
 from repro.wire import PayloadDecodeError
 
+from ..context import Context
 from ..gateway import AllocationError, Gateway, TaskRequest, WorkerHandle
 
 __all__ = ["AsyncGateway"]
@@ -211,7 +212,7 @@ class AsyncGateway(Gateway):
         span = self._rpc_span(handle, req)  # same span contract as _run_on
         t0 = time.monotonic()  # interval math must survive wall-clock steps
         try:
-            result = await self._invoke(handle, req)
+            result = await self._invoke(handle, req, self._wire_ctx(req, span))
         except asyncio.CancelledError:
             raise
         except (ConnectionError, TimeoutError, PayloadDecodeError) as exc:
@@ -223,12 +224,14 @@ class AsyncGateway(Gateway):
             get_tracer().end(span, status=str(result.get("status", "ok")))
         self._on_result(handle, req, result, time.monotonic() - t0)
 
-    async def _invoke(self, handle: WorkerHandle, req: TaskRequest) -> Dict[str, Any]:
+    async def _invoke(
+        self, handle: WorkerHandle, req: TaskRequest, ctx: Context
+    ) -> Dict[str, Any]:
         run_async = getattr(handle.worker, "run_task_async", None)
         if run_async is not None:
-            return await run_async(req.task_name, req.ctx, req.inputs)
+            return await run_async(req.task_name, ctx, req.inputs)
         return await asyncio.get_running_loop().run_in_executor(
-            self._offload, handle.worker.run_task, req.task_name, req.ctx, req.inputs
+            self._offload, handle.worker.run_task, req.task_name, ctx, req.inputs
         )
 
     # -- heartbeats ---------------------------------------------------------

@@ -37,9 +37,12 @@ from collections import Counter
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Iterator, Mapping, Optional, Tuple
 
+from repro.obs.trace import current_span, get_tracer
 from repro.wire import decode_payload, encode_payload, payload_digest
 
 from .context import Context
+
+_TRACER = get_tracer()
 
 __all__ = [
     "Journal",
@@ -258,7 +261,18 @@ class Journal:
                 fh.truncate(good)
 
     # -- append ----------------------------------------------------------------
+    # Traced appends and flushes are ``journal.append`` / ``journal.flush``
+    # spans inside the work that made them (a node, a task); one made outside
+    # any span (requeue bookkeeping, a shutdown flush) is left untimed rather
+    # than opening a one-span trace of its own.
     def append(self, rec: JournalRecord) -> None:
+        if _TRACER.enabled and current_span() is not None:
+            with _TRACER.span("journal.append", attrs={"kind": rec.kind}):
+                self._append(rec)
+        else:
+            self._append(rec)
+
+    def _append(self, rec: JournalRecord) -> None:
         rec.wall_time = rec.wall_time or time.time()  # record timestamp
         body = encode_payload(rec.to_obj())
         frame = _HEADER.pack(len(body), binascii.crc32(body)) + body
@@ -269,6 +283,13 @@ class Journal:
                 os.fsync(self._fh.fileno())
 
     def flush(self) -> None:
+        if _TRACER.enabled and current_span() is not None:
+            with _TRACER.span("journal.flush"):
+                self._flush()
+        else:
+            self._flush()
+
+    def _flush(self) -> None:
         with self._lock:
             self._fh.flush()
             if self.sync != "never":

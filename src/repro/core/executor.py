@@ -916,50 +916,53 @@ class LocalExecutor(_BaseExecutor):
             if tracer.enabled
             else None
         )
-        fn_inputs = unwrap_digested(dict(inputs))
-        retry_limit = node.retry_limit(self.retry.max_attempts - 1)
-        attempt = 0
-        while True:
-            try:
-                if self.journal is not None:
-                    self.journal.append(
-                        JournalRecord(
-                            kind="NODE_START",
-                            node_id=node.id,
-                            context_digest=ctx_d,
-                            input_digest=in_d,
-                            attempt=attempt,
-                        )
-                    )
-                value = node.fn(ctx, **fn_inputs)
-                break
-            except Interrupted:
-                if span is not None:
-                    tracer.end(span, status="interrupt")
-                raise  # suspension request, not a failure: no retry, no NODE_FAIL
-            except Exception:
-                attempt += 1
-                if attempt > retry_limit:
+        # the node span is the current span while the node runs and
+        # commits: its journal appends and the body's own spans nest in it
+        with tracer.use(span):
+            fn_inputs = unwrap_digested(dict(inputs))
+            retry_limit = node.retry_limit(self.retry.max_attempts - 1)
+            attempt = 0
+            while True:
+                try:
                     if self.journal is not None:
                         self.journal.append(
                             JournalRecord(
-                                kind="NODE_FAIL",
+                                kind="NODE_START",
                                 node_id=node.id,
                                 context_digest=ctx_d,
                                 input_digest=in_d,
                                 attempt=attempt,
                             )
                         )
+                    value = node.fn(ctx, **fn_inputs)
+                    break
+                except Interrupted:
                     if span is not None:
-                        tracer.end(span, status="error", attrs={"attempts": attempt})
-                    raise
-                time.sleep(self.retry.delay(attempt))
-        commit_value = value.output if isinstance(value, WithContext) else value
-        facts = dict(value.facts) if isinstance(value, WithContext) else None
-        meta = {"facts": facts} if facts else None
-        self._commit(node.id, ctx_d, in_d, commit_value, attempt, meta=meta,
-                     volatile=node.volatile, expected=expected, deps=node.deps)
-        self._cache_store(node.id, key, ctx_d, in_d, commit_value, facts=facts)
+                        tracer.end(span, status="interrupt")
+                    raise  # suspension request, not a failure: no retry, no NODE_FAIL
+                except Exception:
+                    attempt += 1
+                    if attempt > retry_limit:
+                        if self.journal is not None:
+                            self.journal.append(
+                                JournalRecord(
+                                    kind="NODE_FAIL",
+                                    node_id=node.id,
+                                    context_digest=ctx_d,
+                                    input_digest=in_d,
+                                    attempt=attempt,
+                                )
+                            )
+                        if span is not None:
+                            tracer.end(span, status="error", attrs={"attempts": attempt})
+                        raise
+                    time.sleep(self.retry.delay(attempt))
+            commit_value = value.output if isinstance(value, WithContext) else value
+            facts = dict(value.facts) if isinstance(value, WithContext) else None
+            meta = {"facts": facts} if facts else None
+            self._commit(node.id, ctx_d, in_d, commit_value, attempt, meta=meta,
+                         volatile=node.volatile, expected=expected, deps=node.deps)
+            self._cache_store(node.id, key, ctx_d, in_d, commit_value, facts=facts)
         if span is not None:
             tracer.end(span, attrs={"attempts": attempt + 1})
         return value, "executed"
@@ -1317,43 +1320,44 @@ class ClusterExecutor(_BaseExecutor):
                 else None
             )
             if callable(node.fn):
-                fn_inputs = unwrap_digested(dict(inputs))
-                attempt = 0
-                while True:  # immediate retries: never sleep in the scheduler
-                    try:
-                        value = node.fn(ctx, **fn_inputs)
-                        break
-                    except Interrupted as exc:
-                        if span is not None:
-                            tracer.end(span, status="interrupt")
-                        request_suspend(nid, exc)
-                        return
-                    except Exception:
-                        attempt += 1
-                        if attempt > node.retry_limit(0):
-                            if self.journal is not None:
-                                self.journal.append(
-                                    JournalRecord(
-                                        kind="NODE_FAIL",
-                                        node_id=nid,
-                                        context_digest=ctx_d,
-                                        input_digest=in_d,
-                                        attempt=attempt,
-                                    )
-                                )
-                                self.journal.flush()
+                with tracer.use(span):  # the inline body's spans nest in the node
+                    fn_inputs = unwrap_digested(dict(inputs))
+                    attempt = 0
+                    while True:  # immediate retries: never sleep in the scheduler
+                        try:
+                            value = node.fn(ctx, **fn_inputs)
+                            break
+                        except Interrupted as exc:
                             if span is not None:
-                                tracer.end(span, status="error", attrs={"attempts": attempt})
-                            raise
-                facts = dict(value.facts) if isinstance(value, WithContext) else None
-                meta = {"facts": facts} if facts else None
-                if isinstance(value, WithContext):
-                    ctx = ctx.with_data(value.facts, origin=nid)
-                    value = value.output
-                self._commit(nid, ctx_d, in_d, value, attempt, meta=meta,
-                             volatile=node.volatile, expected=expected,
-                             deps=node.deps)
-                self._cache_store(nid, key, ctx_d, in_d, value, facts=facts)
+                                tracer.end(span, status="interrupt")
+                            request_suspend(nid, exc)
+                            return
+                        except Exception:
+                            attempt += 1
+                            if attempt > node.retry_limit(0):
+                                if self.journal is not None:
+                                    self.journal.append(
+                                        JournalRecord(
+                                            kind="NODE_FAIL",
+                                            node_id=nid,
+                                            context_digest=ctx_d,
+                                            input_digest=in_d,
+                                            attempt=attempt,
+                                        )
+                                    )
+                                    self.journal.flush()
+                                if span is not None:
+                                    tracer.end(span, status="error", attrs={"attempts": attempt})
+                                raise
+                    facts = dict(value.facts) if isinstance(value, WithContext) else None
+                    meta = {"facts": facts} if facts else None
+                    if isinstance(value, WithContext):
+                        ctx = ctx.with_data(value.facts, origin=nid)
+                        value = value.output
+                    self._commit(nid, ctx_d, in_d, value, attempt, meta=meta,
+                                 volatile=node.volatile, expected=expected,
+                                 deps=node.deps)
+                    self._cache_store(nid, key, ctx_d, in_d, value, facts=facts)
                 if span is not None:
                     tracer.end(span, attrs={"attempts": attempt + 1})
                 finish(nid, value, ctx, "executed")
@@ -1530,12 +1534,13 @@ class ClusterExecutor(_BaseExecutor):
                         del inflight[nid]
                         span = node_spans.pop(nid, None)
                     self.straggler.finished(str(st.node.fn), nid)
-                    self._commit(
-                        nid, st.ctx_digest, st.input_digest, value,
-                        requeues + copies - 1,
-                        volatile=st.node.volatile, expected=st.expected,
-                        deps=st.node.deps,
-                    )
+                    with tracer.use(span):  # the commit's append nests in the node
+                        self._commit(
+                            nid, st.ctx_digest, st.input_digest, value,
+                            requeues + copies - 1,
+                            volatile=st.node.volatile, expected=st.expected,
+                            deps=st.node.deps,
+                        )
                     self._cache_store(
                         nid, st.cache_key, st.ctx_digest, st.input_digest, value
                     )

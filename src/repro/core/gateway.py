@@ -25,7 +25,7 @@ from concurrent.futures import Future, InvalidStateError
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 
-from repro.obs.trace import extract_trace, get_tracer
+from repro.obs.trace import extract_trace, get_tracer, inject_trace
 from repro.wire import PayloadDecodeError, unwrap_digested
 
 from .context import Context, EMPTY_CONTEXT
@@ -66,7 +66,7 @@ class TaskRequest:
     priority: int = 0  # lower = more urgent (silo key)
     affinity_key: str = ""  # context-affinity routing hint
     future: Future = field(default_factory=Future)
-    submitted_at: float = field(default_factory=time.time)
+    submitted_at: float = field(default_factory=time.monotonic)  # queueing math
     attempts: int = 0  # failure budget: real execution failures/evictions
     backoffs: int = 0  # empty-pool waits — NOT charged to the budget
     max_attempts: int = 3
@@ -482,6 +482,7 @@ class Gateway:
         Parent identity is read from the obs fact riding ``req.ctx`` — the
         same context that crosses the wire — so the span chain survives
         resubmission, speculation copies, and sharded-gateway handoffs.
+        ``queued_s`` is the time from submit to this dispatch.
         """
         tracer = get_tracer()
         if not tracer.enabled:
@@ -497,8 +498,14 @@ class Gateway:
                 "task": req.task_name,
                 "node": str(req.meta.get("node", "")),
                 "attempt": req.attempts,
+                "queued_s": time.monotonic() - req.submitted_at,
             },
         )
+
+    @staticmethod
+    def _wire_ctx(req: TaskRequest, span: Any) -> Context:
+        """The context sent to the worker: the rpc span, if any, is the parent."""
+        return req.ctx if span is None else inject_trace(req.ctx, span)
 
     def _run_on(self, handle: WorkerHandle, req: TaskRequest) -> None:
         with self._track_lock:
@@ -507,7 +514,7 @@ class Gateway:
         span = self._rpc_span(handle, req)
         t0 = time.monotonic()  # interval math must survive wall-clock steps
         try:
-            result = handle.worker.run_task(req.task_name, req.ctx, req.inputs)
+            result = handle.worker.run_task(req.task_name, self._wire_ctx(req, span), req.inputs)
         except (ConnectionError, TimeoutError, PayloadDecodeError) as exc:
             if span is not None:
                 get_tracer().end(span, status="error", attrs={"error": type(exc).__name__})

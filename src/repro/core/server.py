@@ -129,9 +129,10 @@ def _execute(
         kind="task",
         attrs={"task": task_name},
     )
-    result = _execute_inner(
-        registry, middleware, state, task_name, ctx, inputs, fail_injector
-    )
+    with tracer.use(span):  # the task body's own spans nest in it
+        result = _execute_inner(
+            registry, middleware, state, task_name, ctx, inputs, fail_injector
+        )
     tracer.end(
         span,
         status=str(result.get("status", "error")),

@@ -19,24 +19,30 @@ from repro.core import (Context, Gateway, TaskRegistry, WorkerClient,
                         WorkerServer)
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build
+from repro.obs.trace import get_tracer
 
 
 def build_registry(cfg, model, params) -> TaskRegistry:
     reg = TaskRegistry()
     decode = jax.jit(model.decode_step)
+    tracer = get_tracer()
 
     @reg.task("generate")
     def generate(ctx, prompt, new_tokens):
-        toks = jnp.asarray(np.asarray(prompt, np.int32))[None, :]
-        S = toks.shape[1]
-        logits, cache = model.prefill(params, {"tokens": toks},
-                                      pad_to=S + int(new_tokens))
-        tok = jnp.argmax(logits, axis=-1)
-        out = []
-        for _ in range(int(new_tokens)):
-            out.append(int(tok[0]))
-            logits, cache = decode(params, cache, {"token": tok})
+        n = int(new_tokens)
+        # serve.prefill: the prompt on the host to the first token on the host
+        with tracer.span("serve.prefill", attrs={"prompt_tokens": len(prompt)}):
+            toks = jnp.asarray(np.asarray(prompt, np.int32))[None, :]
+            logits, cache = model.prefill(params, {"tokens": toks},
+                                          pad_to=toks.shape[1] + n)
             tok = jnp.argmax(logits, axis=-1)
+            out = [int(tok[0])] if n else []
+        with tracer.span("serve.decode", attrs={"tokens": n}):
+            for i in range(n):
+                logits, cache = decode(params, cache, {"token": tok})
+                tok = jnp.argmax(logits, axis=-1)
+                if i + 1 < n:
+                    out.append(int(tok[0]))
         return {"tokens": out}
 
     @reg.task("health")

@@ -32,9 +32,11 @@ import importlib
 _EXPORTS = {
     "Span": "trace",
     "Tracer": "trace",
+    "current_span": "trace",
     "extract_trace": "trace",
     "get_tracer": "trace",
     "inject_trace": "trace",
+    "jax_counts": "trace",
     "strip_trace": "trace",
     "Counter": "metrics",
     "Gauge": "metrics",

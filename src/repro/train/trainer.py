@@ -47,6 +47,7 @@ from repro.core import (
     WithContext,
 )
 from repro.obs.metrics import metrics as obs_metrics
+from repro.obs.trace import get_tracer
 from repro.wire import canonical_digest, payload_digest
 from repro.data.pipeline import DataConfig, TokenSource
 from repro.launch.mesh import make_mesh
@@ -56,6 +57,8 @@ from repro.sharding.specs import ShardingOptions, ShardingRules
 from .steps import make_train_step
 
 __all__ = ["TrainConfig", "Trainer"]
+
+_TRACER = get_tracer()
 
 
 @dataclass
@@ -128,8 +131,9 @@ class Trainer:
 
     def device_batch(self, step: int) -> Dict[str, jax.Array]:
         """Step ``step``'s batch on the mesh, split along ``data``."""
-        batch = self.source.batch_at(step)
-        return jax.device_put(batch, self.rules.batch_spec(batch))
+        with _TRACER.span("train.batch", attrs={"step": step, "rows": self.tc.global_batch}):
+            batch = self.source.batch_at(step)
+            return jax.device_put(batch, self.rules.batch_spec(batch))
 
     # -- recovery ------------------------------------------------------------
     def recover(self) -> Tuple[int, Any, Any]:
@@ -218,11 +222,14 @@ class Trainer:
                     # replay-verification: run the NON-donating twin so a
                     # digest mismatch leaves the restored state intact
                     step_fn = self._train_step_verify
-                new_params, new_opt, metrics = step_fn(state["params"], state["opt"], jbatch)
-                out = {k: float(v) for k, v in metrics.items()}
+                with _TRACER.span("train.step"):  # dispatch
+                    new_params, new_opt, metrics = step_fn(state["params"], state["opt"], jbatch)
+                with _TRACER.span("train.sync"):  # waits for the device
+                    out = {k: float(v) for k, v in metrics.items()}
                 out["step"] = _s
                 out["data_digest"] = meta["digest"]
-                got = payload_digest(out)
+                with _TRACER.span("train.digest"):
+                    got = payload_digest(out)
                 if want is not None and want != got:
                     raise RuntimeError(
                         f"non-deterministic replay at step {_s}: "

@@ -46,9 +46,11 @@ from repro.obs.sinks import JsonlSink, RingSink, chrome_trace, read_spans
 from repro.obs.timeline import Timeline
 from repro.obs.trace import (
     TRACE_KEY,
+    current_span,
     extract_trace,
     get_tracer,
     inject_trace,
+    jax_counts,
     strip_trace,
 )
 
@@ -234,12 +236,15 @@ def test_span_propagation_over_http_worker(tmp_path):
     assert len(node_spans) == kinds["NODE_COMMIT"] == len(graph.nodes)
     assert {sp["attrs"]["node"] for sp in node_spans} == set(graph.nodes)
     assert all(sp["parent"] == run_span["span"] for sp in node_spans)
-    # rpc + worker-side task spans hang off the node spans (gateway-dispatched
-    # "mul2" nodes; the lambda seed runs inline without an rpc hop)
+    # rpc spans hang off the node spans (gateway-dispatched "mul2" nodes; the
+    # lambda seed runs inline without an rpc hop), worker-side task spans off
+    # the rpc that carried them
     node_ids = {sp["span"] for sp in node_spans}
     assert by_kind["rpc"] and all(sp["parent"] in node_ids for sp in by_kind["rpc"])
-    assert by_kind["task"] and all(sp["parent"] in node_ids for sp in by_kind["task"])
+    rpc_ids = {sp["span"] for sp in by_kind["rpc"]}
+    assert by_kind["task"] and all(sp["parent"] in rpc_ids for sp in by_kind["task"])
     assert all(sp["attrs"]["worker"] == "w0" for sp in by_kind["rpc"])
+    assert all(sp["attrs"]["queued_s"] >= 0.0 for sp in by_kind["rpc"])
 
 
 def test_span_propagation_over_asyncio_transport(tmp_path):
@@ -263,7 +268,9 @@ def test_span_propagation_over_asyncio_transport(tmp_path):
     task = [sp for sp in spans if sp["kind"] == "task"]
     node_ids = {sp["span"] for sp in node_spans}
     assert rpc and all(sp["parent"] in node_ids for sp in rpc)
-    assert task and all(sp["parent"] in node_ids for sp in task)
+    rpc_ids = {sp["span"] for sp in rpc}
+    assert task and all(sp["parent"] in rpc_ids for sp in task)
+    assert all(sp["attrs"]["queued_s"] >= 0.0 for sp in rpc)
 
 
 def test_replica_kill_handoff_keeps_one_coherent_trace(tmp_path, faults):
@@ -471,3 +478,195 @@ def test_timeline_posthoc_on_compacted_journal(tmp_path):
     assert all(nt.status == "committed" for nt in after.nodes.values())
     nodes, _dur = after.critical_path()
     assert nodes  # dependency chain still reconstructable
+
+
+# ---------------------------------------------------------------------------
+# spans on the device trace's clock, the current span, lowering counters
+# ---------------------------------------------------------------------------
+
+
+def test_spans_reach_the_profiler_host_plane(tmp_path):
+    """Each span is a TraceAnnotation: its name lands on the xplane's host
+    plane, also when the span ends on another thread."""
+    import glob
+    import threading
+
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    tracer = get_tracer()
+    ring = RingSink()
+    step = jax.jit(lambda x: x * 2.0 + 1.0)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tracer.attached(ring):
+            with tracer.span("obs.outer"):
+                step(jnp.ones(8)).block_until_ready()
+            handed = tracer.start_span("obs.handed_over")
+            ender = threading.Thread(target=tracer.end, args=(handed,))
+            ender.start()
+            ender.join()
+    finally:
+        jax.profiler.stop_trace()
+    [path] = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = {
+        e.name
+        for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for e in line.events
+    }
+    assert {"obs.outer", "obs.handed_over"} <= host
+    assert {s["name"] for s in ring.spans()} == {"obs.outer", "obs.handed_over"}
+    # ended elsewhere: no lowering deltas, which belong to the opening thread
+    handed_obj = next(s for s in ring.spans() if s["name"] == "obs.handed_over")
+    assert "jax_lowerings" not in handed_obj["attrs"]
+
+
+def test_current_span_parents_spans_opened_without_one():
+    tracer = get_tracer()
+    ring = RingSink()
+    with tracer.attached(ring):
+        root = tracer.start_span("task:x", kind="task")
+        with tracer.use(root):
+            with tracer.span("inner") as inner:
+                assert current_span() is inner
+                leaf = tracer.start_span("leaf")
+                tracer.end(leaf)
+            assert current_span() is root
+        assert current_span() is None
+        tracer.end(root)
+        lone = tracer.start_span("lone")
+        tracer.end(lone)
+    by_name = {s["name"]: s for s in ring.spans()}
+    assert by_name["inner"]["parent"] == by_name["task:x"]["span"]
+    assert by_name["leaf"]["parent"] == by_name["inner"]["span"]
+    assert {s["trace"] for n, s in by_name.items() if n != "lone"} == {root.trace_id}
+    assert by_name["lone"]["parent"] == "" and by_name["lone"]["trace"] != root.trace_id
+
+
+def _toy_serving_model():
+    """Stand-in for a decoder with ``launch.serve.build_registry``'s surface."""
+    import jax
+    import jax.numpy as jnp
+
+    class Toy:
+        def prefill(self, params, batch, pad_to):
+            last = batch["tokens"][:, -1]
+            return jax.nn.one_hot((last + params) % 11, 11), jnp.zeros((), jnp.int32)
+
+        def decode_step(self, params, cache, batch):
+            return jax.nn.one_hot((batch["token"] + cache + 1) % 11, 11), cache + 1
+
+    return Toy()
+
+
+def test_serve_spans_nest_under_task_generate():
+    """serve.prefill and serve.decode find task:generate as the current span;
+    the task span is the child of the gateway's rpc span."""
+    from repro.launch.serve import build_registry
+
+    reg = build_registry(None, _toy_serving_model(), 3)
+    tracer = get_tracer()
+    ring = RingSink()
+    with Gateway([InProcWorker("w0", reg)]) as gw:
+        with tracer.attached(ring):
+            outs = [
+                gw.submit("generate", inputs={"prompt": [1, 2, 5], "new_tokens": 4}).result(
+                    timeout=60
+                )
+                for _ in range(2)
+            ]
+    assert outs[0] == outs[1] == {"tokens": [8, 9, 0, 3]}
+    spans = ring.spans()
+    tasks = {s["span"]: s for s in spans if s["name"] == "task:generate"}
+    rpcs = {s["span"]: s for s in spans if s["name"] == "rpc:generate"}
+    assert len(tasks) == len(rpcs) == 2
+    assert all(t["parent"] in rpcs for t in tasks.values())
+    for name, attr, value in (("serve.prefill", "prompt_tokens", 3), ("serve.decode", "tokens", 4)):
+        got = [s for s in spans if s["name"] == name]
+        assert len(got) == 2 and all(s["parent"] in tasks for s in got)
+        assert all(s["attrs"][attr] == value for s in got)
+    # the first request lowers at least the jitted decode; the second finds
+    # everything cached
+    lowered = sorted(t["attrs"]["jax_lowerings"] for t in tasks.values())
+    assert lowered[0] == 0 and lowered[1] >= 1
+
+
+def test_lowering_counter_is_per_thread_and_off_when_untraced():
+    import threading
+
+    import jax
+
+    tracer = get_tracer()
+    before = jax_counts()
+    jax.jit(lambda x: x + 1.0)(1.0)  # tracing off: not counted
+    assert jax_counts() == before
+    ring = RingSink()
+    seen = {}
+
+    def other_thread():
+        start = jax_counts()
+        jax.jit(lambda x: x * 3.0)(2.0)
+        seen["delta"] = tuple(b - a for a, b in zip(start, jax_counts()))
+
+    with tracer.attached(ring):
+        worker = threading.Thread(target=other_thread)
+        worker.start()
+        worker.join()
+        assert jax_counts() == before  # the other thread's lowering is its own
+        with tracer.span("lowers"):
+            jax.jit(lambda x: x - 5.0)(1.0)
+        with tracer.span("cached"):
+            jax.jit(lambda x: x * 3.0)  # nothing called: nothing lowered
+    assert seen["delta"] == (1, 1)
+    attrs = {s["name"]: s["attrs"] for s in ring.spans()}
+    assert (attrs["lowers"]["jax_lowerings"], attrs["lowers"]["jax_compiles"]) == (1, 1)
+    assert (attrs["cached"]["jax_lowerings"], attrs["cached"]["jax_compiles"]) == (0, 0)
+
+
+def test_traced_training_commits_the_untraced_journal(tmp_path, monkeypatch):
+    """train.* and journal.* spans, and the same journal records, byte for
+    byte, as an untraced run."""
+    import types
+
+    import repro.core.durable as durable
+    from repro.configs import get_config, smoke_variant
+    from repro.optim.adamw import AdamWConfig
+    from repro.train.trainer import TrainConfig, Trainer
+
+    # record timestamps are the one field two runs may differ in
+    monkeypatch.setattr(durable, "time", types.SimpleNamespace(time=lambda: 1.0e9))
+    cfg = smoke_variant(get_config("serpytor-demo-100m"))
+
+    def train(run_dir):
+        tc = TrainConfig(run_dir=str(run_dir), num_steps=3, checkpoint_every=3, log_every=100,
+                         global_batch=2, seq_len=16, heartbeat=False, journal_sync="batch",
+                         opt=AdamWConfig(lr=1e-3, warmup_steps=1, total_steps=3))
+        Trainer(cfg, tc).train()
+        with open(run_dir / "journal.wal", "rb") as fh:
+            data = fh.read()
+        # concurrent data@N nodes commit in any order: compare the records
+        frames, off = [], 0
+        while off < len(data):
+            size, _crc = durable._HEADER.unpack_from(data, off)
+            end = off + durable._HEADER.size + size
+            frames.append(data[off:end])
+            off = end
+        return sorted(frames)
+
+    plain = train(tmp_path / "plain")
+    ring = RingSink()
+    with get_tracer().attached(ring):
+        traced = train(tmp_path / "traced")
+    assert traced == plain
+    spans = ring.spans()
+    steps = {s["span"] for s in spans if s["name"].startswith("step@")}
+    assert len(steps) == 3
+    for name in ("train.batch", "train.step", "train.sync", "train.digest"):
+        got = [s for s in spans if s["name"] == name]
+        assert len(got) == 3 and all(s["parent"] in steps for s in got), name
+    kinds = [s["attrs"]["kind"] for s in spans
+             if s["name"] == "journal.append" and s["parent"] in steps]
+    assert kinds.count("NODE_START") == kinds.count("NODE_COMMIT") == 3
