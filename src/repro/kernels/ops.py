@@ -88,8 +88,12 @@ def _per_shard(kernel, args, in_dims, out_dims):
 
 def flash_attention(q, k, v, *, causal: bool = True, window: Optional[int] = None,
                     scale: Optional[float] = None, impl: str = "auto",
-                    block_q: int = 128, block_k: int = 128) -> jnp.ndarray:
-    """Causal/local GQA attention. q:(B,Hq,Sq,D) k,v:(B,Hkv,Sk,D) → (B,Hq,Sq,D)."""
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None) -> jnp.ndarray:
+    """Causal/local GQA attention. q:(B,Hq,Sq,D) k,v:(B,Hkv,Sk,D) → (B,Hq,Sq,D).
+
+    ``block_q``/``block_k`` None: the kernel chooses its tiles from the shape.
+    """
     impl = _resolve(impl)
     if impl == "dense":
         return _ref.flash_attention_dense_ref(q, k, v, causal=causal, window=window,

@@ -9,7 +9,9 @@ import pytest
 from _propcheck import given, settings, st
 
 from repro.kernels import ops, ref
+from repro.kernels import flash_attention as fa
 from repro.kernels.flash_attention import flash_attention_pallas
+from repro.obs.metrics import metrics
 
 RNG = np.random.default_rng(7)
 
@@ -22,26 +24,45 @@ def rand(shape, dtype=jnp.float32, scale=1.0):
 # flash attention
 # ---------------------------------------------------------------------------
 FLASH_CASES = [
-    # (B, Hq, Hkv, Sq, Sk, D, causal, window, dtype)
-    (1, 2, 2, 128, 128, 64, True, None, jnp.float32),
-    (2, 4, 2, 128, 128, 64, True, None, jnp.float32),    # GQA
-    (1, 8, 1, 256, 256, 128, True, None, jnp.float32),   # MQA
-    (1, 2, 2, 128, 128, 64, False, None, jnp.float32),   # bidirectional
-    (1, 2, 2, 128, 128, 64, True, 64, jnp.float32),      # local window
-    (1, 2, 1, 100, 100, 32, True, None, jnp.float32),    # ragged (pad path)
-    (1, 2, 2, 64, 192, 32, True, None, jnp.float32),     # Sq < Sk (chunked q)
-    (1, 2, 2, 128, 128, 64, True, None, jnp.bfloat16),
+    # (B, Hq, Hkv, Sq, Sk, D, Dv, causal, window, dtype, block_q, block_k);
+    # None tiles are chosen from the shape.
+    (1, 2, 2, 128, 128, 64, 64, True, None, jnp.float32, 64, 64),
+    (2, 4, 2, 128, 128, 64, 64, True, None, jnp.float32, 64, 64),    # GQA
+    (1, 8, 1, 256, 256, 128, 128, True, None, jnp.float32, 64, 64),  # MQA
+    (1, 2, 2, 128, 128, 64, 64, False, None, jnp.float32, 64, 64),   # bidirectional
+    (1, 2, 2, 128, 128, 64, 64, True, 64, jnp.float32, 64, 64),      # local window
+    (1, 2, 1, 100, 100, 32, 32, True, None, jnp.float32, 64, 64),    # ragged (pad path)
+    (1, 2, 2, 64, 192, 32, 32, True, None, jnp.float32, 64, 64),     # Sq < Sk (chunked q)
+    (1, 2, 2, 128, 128, 64, 64, True, None, jnp.bfloat16, 64, 64),
+    # the block plan skips blocks, clamps the kv DMA, masks only edge blocks
+    (1, 2, 2, 256, 256, 32, 32, True, None, jnp.float32, 64, 64),     # causal, 4 q blocks
+    (1, 2, 2, 256, 256, 32, 32, True, None, jnp.float32, 32, 128),    # bq < bk
+    (1, 2, 2, 256, 256, 32, 32, True, None, jnp.float32, 128, 32),    # bq > bk
+    (1, 2, 2, 96, 256, 32, 32, True, None, jnp.float32, 32, 64),      # Sq < Sk, right-aligned
+    (1, 2, 2, 90, 170, 32, 32, True, None, jnp.float32, 32, 64),      # neither a tile multiple
+    (1, 2, 2, 256, 256, 32, 32, True, 20, jnp.float32, 64, 64),       # window < tile
+    (1, 2, 2, 256, 256, 32, 32, True, 150, jnp.float32, 64, 64),      # window > tile
+    (1, 2, 2, 100, 230, 32, 32, True, 70, jnp.float32, 32, 64),       # window, Sq < Sk, ragged
+    (2, 4, 2, 192, 192, 32, 32, True, None, jnp.float32, 64, 64),     # GQA group 2
+    (1, 8, 2, 192, 192, 32, 32, True, 40, jnp.float32, 64, 64),       # GQA group 4, window
+    (1, 2, 2, 192, 192, 48, 32, True, None, jnp.float32, 64, 64),     # MLA: D != Dv
+    (1, 2, 2, 64, 200, 32, 32, False, None, jnp.float32, 32, 64),     # cross: padded keys only
+    (1, 2, 2, 128, 128, 32, 32, False, 40, jnp.float32, 32, 32),      # bidirectional window
+    (1, 4, 2, 256, 256, 64, 64, True, None, jnp.bfloat16, 64, 64),    # bf16 operands
+    (1, 2, 2, 200, 200, 64, 64, True, 50, jnp.bfloat16, 64, 64),      # bf16, window, ragged
+    (1, 2, 1, 17, 17, 32, 32, True, None, jnp.bfloat16, None, None),  # whole length
 ]
 
 
 @pytest.mark.parametrize("case", FLASH_CASES)
 def test_flash_kernel_matches_dense_oracle(case):
-    B, Hq, Hkv, Sq, Sk, D, causal, window, dtype = case
+    B, Hq, Hkv, Sq, Sk, D, Dv, causal, window, dtype, bq, bk = case
     q = rand((B, Hq, Sq, D), dtype)
     k = rand((B, Hkv, Sk, D), dtype)
-    v = rand((B, Hkv, Sk, D), dtype)
-    out = flash_attention_pallas(q, k, v, causal, window, None, 64, 64, True)
+    v = rand((B, Hkv, Sk, Dv), dtype)
+    out = flash_attention_pallas(q, k, v, causal, window, None, bq, bk, True)
     want = ref.flash_attention_dense_ref(q, k, v, causal=causal, window=window)
+    assert out.shape == (B, Hq, Sq, Dv) and out.dtype == dtype
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32), rtol=tol, atol=tol)
@@ -56,6 +77,83 @@ def test_flash_kernel_mla_head_dims():
     want = ref.flash_attention_dense_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+def _dense_mask(sq, sk, causal, window):
+    qpos = np.arange(sq)[:, None] + (sk - sq)
+    kpos = np.arange(sk)[None, :]
+    keep = np.ones((sq, sk), bool)
+    if causal:
+        keep &= kpos <= qpos
+    if window is not None:
+        keep &= kpos > qpos - window
+    return keep
+
+
+def _flash_blocks():
+    reg = metrics()
+    return (reg.counter("repro_kernel_flash_blocks_total", kind="visited").value,
+            reg.counter("repro_kernel_flash_blocks_total", kind="total").value)
+
+
+@pytest.mark.parametrize("causal, window", [(True, None), (True, 1), (True, 20), (True, 100),
+                                            (False, None), (False, 30)])
+def test_flash_block_plan_is_exact(causal, window):
+    """A block pair is visited iff some entry of its real rows is unmasked,
+    takes the unmasked path iff none is masked or padded, its clamped kv
+    index is itself when visited and in range always, and the counter adds up."""
+    for sq, sk in [(1, 1), (17, 17), (40, 96), (96, 96), (70, 130), (130, 130)]:
+        keep = _dense_mask(sq, sk, causal, window)
+        for bq, bk in [(16, 16), (16, 48), (48, 16), (32, 32)]:
+            nq, nk = -(-sq // bq), -(-sk // bk)
+            plan = fa._block_plan(nq, bq, bk, sq, sk, causal, window)
+            for iq in range(nq):
+                lo, hi, hi_dma, whole_lo, whole_hi = plan[:, iq]
+                for ik in range(nk):
+                    blk = keep[iq * bq:(iq + 1) * bq, ik * bk:(ik + 1) * bk]
+                    visit = lo <= ik <= hi
+                    assert visit == blk.any(), (sq, sk, bq, bk, iq, ik)
+                    whole = blk.all() and (ik + 1) * bk <= sk
+                    assert (whole_lo <= ik <= whole_hi) == whole, (sq, sk, bq, bk, iq, ik)
+                    clamped = min(max(ik, lo), hi_dma)
+                    assert 0 <= clamped < nk and (clamped == ik or not visit)
+
+    B, H, sq, sk, bq, bk = 2, 3, 70, 130, 32, 48
+    before = _flash_blocks()
+    x = jax.ShapeDtypeStruct((B, H, sq, 16), jnp.float32)
+    kv = jax.ShapeDtypeStruct((B, H, sk, 16), jnp.float32)
+    jax.eval_shape(lambda q, k, v: flash_attention_pallas(q, k, v, causal, window, None,
+                                                          bq, bk, True), x, kv, kv)
+    after = _flash_blocks()
+    want = _dense_mask(sq, sk, causal, window)
+    nq, nk = -(-sq // bq), -(-sk // bk)
+    blocks = sum(want[i * bq:(i + 1) * bq, j * bk:(j + 1) * bk].any()
+                 for i in range(nq) for j in range(nk))
+    assert after[0] - before[0] == B * H * blocks
+    assert after[1] - before[1] == B * H * nq * nk
+
+
+@pytest.mark.parametrize("sq, sk, d, dv, itemsize, want", [
+    (2048, 2048, 64, 64, 2, (1024, 1024)),      # stablelm-1.6b training call
+    (4096, 4096, 128, 128, 2, (1024, 1024)),    # qwen3-1.7b longest prefill
+    (17, 17, 128, 128, 2, (17, 17)),            # short, unaligned: whole length
+    (300, 1000, 64, 64, 4, (300, 1000)),
+    (3000, 3000, 64, 64, 2, (1024, 1024)),      # pads 72, the least of any tile
+    (2048, 2048, 64, 64, 4, (512, 512)),        # float32 tiles: budget binds
+    (2048, 2048, 192, 128, 2, (512, 512)),      # MLA widths
+    (1, 32768, 128, 128, 2, (1, 1024)),
+    (8192, 8192, 1024, 1024, 4, None),          # the budget's floor
+])
+def test_pick_blocks_from_shape(sq, sk, d, dv, itemsize, want):
+    bq, bk = fa._pick_blocks(sq, sk, d, dv, itemsize)
+    if want is not None:
+        assert (bq, bk) == want
+    for n, t in ((sq, bq), (sk, bk)):
+        assert t == n or (t % 128 == 0 and 0 <= (-n) % t < t)
+        if t != n:
+            assert (-n) % t == min((-n) % c for c in range(128, t + 1, 128))
+    if (bq, bk) != (min(sq, 128), min(sk, 128)):
+        assert fa._vmem_bytes(bq, bk, d, dv, itemsize) <= fa._VMEM_BUDGET
 
 
 def test_flash_kernel_grad_matches_oracle_grad():

@@ -68,6 +68,8 @@ def _assert_kernel(compiled):
     [
         ("serpytor-demo-100m prefill", (4, 12, 2048, 64), (4, 4, 2048, 64), jnp.float32),
         ("qwen3-1.7b unaligned prompt", (1, 16, 17, 128), (1, 8, 17, 128), jnp.bfloat16),
+        ("stablelm-1.6b training call", (2, 32, 2048, 64), (2, 32, 2048, 64), jnp.bfloat16),
+        ("qwen3-1.7b longest prefill", (1, 16, 4096, 128), (1, 8, 4096, 128), jnp.bfloat16),
     ],
 )
 def test_flash_attention_compiles(chip, name, q, kv, dtype):
