@@ -1,6 +1,7 @@
 """The numbers that decide ``correct``: gaps between the program and the reference.
 
-Training (first steps, by the worst leaf; each stacked layer is a leaf):
+Training (first steps, by the worst leaf; each layer of a stacked segment,
+``seg<k>/...``, is a leaf):
 
 * ``loss_gap``: largest relative gap of a step's loss;
 * ``grad_gap``: gap between the norms of the first clipped gradient, program
@@ -21,8 +22,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import model as bm
-
 #: a leaf whose reference gradient norm is under this share of the median
 #: leaf's is left out of ``change_gap``
 NOUGHT = 1e-3
@@ -32,20 +31,26 @@ def _flat_norms(res: Dict[str, np.ndarray]) -> Dict[str, float]:
     out: Dict[str, float] = {}
     for name, vals in res.items():
         vals = np.asarray(vals, np.float64)
-        if name.startswith("seg0/"):
+        if _stacked(name.split("/", 1)[0]):
             out.update({f"{name}[{i}]": float(x) for i, x in enumerate(vals)})
         else:
             out[name] = float(vals[0])
     return out
 
 
+def _stacked(top: str) -> bool:
+    """Whether a top-level key holds layers stacked along a leading axis."""
+    return top.startswith("seg")
+
+
 @jax.jit
 def _norms(tree):
     out = {}
-    for path, a in bm.leaf_paths(tree):
+    for path, a in jax.tree_util.tree_leaves_with_path(tree):
+        names = [k.key for k in path]
         x = a.astype(jnp.float32)
-        x = x.reshape(x.shape[0], -1) if path[0] == "seg0" else x.reshape(1, -1)
-        out["/".join(path)] = jnp.sqrt(jnp.sum(x * x, axis=1))
+        x = x.reshape(x.shape[0], -1) if _stacked(names[0]) else x.reshape(1, -1)
+        out["/".join(names)] = jnp.sqrt(jnp.sum(x * x, axis=1))
     return out
 
 

@@ -7,9 +7,11 @@ The benchmark's own runs never run this. For each seed it prints one JSON
 line with the numbers that ``correct`` compares:
 
 * training cells: the plain reference put in the program's place with its
-  parameters stored in bfloat16 (the control: no float32 master copy), and
-  with half of each batch left out (a fault), each against the float32
-  reference;
+  parameters stored in bfloat16 (the control: no float32 master copy), with
+  half of each batch left out (a fault) and, for a cell on several chips,
+  with the exchange between chips left out (a fault: each chip steps on its
+  own rows, and the first chip's are read), each against the float32
+  reference. These readings run the reference alone, on one chip;
 * serving cells: a run of the cell (the program's own gap) and, on the same
   sample of answers, the gap of the tokens that the reference with its
   matrices in int8 or float8 puts first (the control).
@@ -20,6 +22,7 @@ or more and the smallest control reading (``PERF.md`` lists both).
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import sys
 import time
@@ -30,18 +33,23 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 
 def train_readings(cell, seed: int) -> dict:
-    from bench import compare, inputs
+    from bench import compare, harness, inputs
     from bench.reference import train as ref_train
 
     cfg, mix = cell.config, cell.traffic
+    family = harness.load_family(cell)
+    rows = mix["global_batch"]
     batches = [inputs.token_batch(seed, s, vocab=cfg["vocab_size"], seq_len=mix["seq_len"],
-                                  rows=mix["global_batch"]) for s in range(3)]
-    ref = ref_train.run(cfg, seed, batches, mix["optimizer"])
+                                  rows=rows) for s in range(3)]
+    ref = ref_train.run(family, cfg, seed, batches, mix["optimizer"])
     out = {"seed": seed}
-    for name, kw in (("control_bf16_params", {"param_dtype": "bfloat16"}),
-                     ("fault_half_batch", {"rows": mix["global_batch"] // 2})):
+    variants = [("control_bf16_params", {"param_dtype": "bfloat16"}),
+                ("fault_half_batch", {"rows": rows // 2})]
+    if cell.chips > 1:
+        variants.append(("fault_no_exchange", {"rows": rows // cell.chips}))
+    for name, kw in variants:
         t = time.perf_counter()
-        other = ref_train.run(cfg, seed, batches, mix["optimizer"], **kw)
+        other = ref_train.run(family, cfg, seed, batches, mix["optimizer"], **kw)
         gaps = compare.train_gaps(other, ref)
         out[name] = {k: gaps[k] for k in ("loss_gap", "grad_gap", "change_gap",
                                           "_grad_leaf", "_change_leaf")}
@@ -74,7 +82,7 @@ def main() -> None:
     from bench import harness
 
     cell = harness.find_cell(args.workload)
-    harness.check_chips(cell)
+    harness.check_chips(dataclasses.replace(cell, chips=1) if cell.driver == "train" else cell)
     harness.configure_jax()
     cell.seconds = args.seconds
     for seed in (int(s) for s in args.seeds.split(",")):

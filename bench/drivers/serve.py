@@ -25,9 +25,7 @@ from typing import Any, Dict, List, Optional, Sequence
 import numpy as np
 
 from bench import compare, inputs
-from bench import model as bm
-from bench.harness import Cell, Check, GcPauses, Outcome, Run
-from bench.reference import decoder
+from bench.harness import Cell, Check, GcPauses, Outcome, Run, load_family
 
 #: seconds past the window's close that the driver waits for answers
 GRACE_S = 60.0
@@ -47,10 +45,11 @@ def run(cell: Cell, run: Run) -> Outcome:
     cfg, mix, st = cell.config, cell.traffic, cell.settings
     seed = cell.seed
     vocab = cfg["vocab_size"]
-    mcfg = bm.program_config(cfg, attn_impl=st.get("attn_impl", "auto"))
+    family = load_family(cell)
+    mcfg = family.program_config(cfg, attn_impl=st.get("attn_impl", "auto"))
     model = build(mcfg)
-    bm.check_layout(cfg, model)
-    params = bm.make_weights(cfg, seed)
+    family.check_layout(cfg, model)
+    params = family.make_weights(cfg, seed)
     regs = [build_registry(mcfg, model, params) for _ in range(int(mix["workers"]))]
     for i, reg in enumerate(regs):
         for r in inputs.warmup_requests(mix, seed=seed, vocab=vocab):
@@ -171,7 +170,7 @@ def run(cell: Cell, run: Run) -> Outcome:
     run.note(f"gateway in the window: {gw_moved}; {pauses.summary()}")
 
     sample = sample_requests(reqs, ok, int(st["check_requests"]), seed)
-    gaps = check_sample(cfg, seed, sample, st.get("controls", ()))
+    gaps = check_sample(family, cfg, seed, sample, st.get("controls", ()))
     run.mark("reference check")
     limits = st["limits"]
     checks = {"logit_gap": Check(gaps["logit_gap"], float(limits["logit_gap"]))}
@@ -208,9 +207,9 @@ def sample_requests(reqs: List[inputs.Request], ok: Dict[int, List[int]], k: int
     return [(r.prompt, ok[r.index]) for r in pick]
 
 
-def check_sample(cfg: Dict[str, Any], seed: int, sample: List[tuple],
+def check_sample(family, cfg: Dict[str, Any], seed: int, sample: List[tuple],
                  controls: Sequence[str] = ()) -> Dict[str, float]:
-    """Gap of the served tokens under the float32 reference.
+    """Gap of the served tokens under the float32 reference of ``family``.
 
     For each precision in ``controls`` also the gap of the tokens that the
     reference computed in that precision puts first (``control_<p>``).
@@ -219,8 +218,8 @@ def check_sample(cfg: Dict[str, Any], seed: int, sample: List[tuple],
 
     if not sample:
         return {"logit_gap": math.inf}
-    params = bm.make_weights(cfg, seed)
-    ref = decoder.position_logits(cfg)
+    params = family.make_weights(cfg, seed)
+    ref = family.position_logits(cfg)
     out = {"logit_gap": 0.0}
     out.update({f"control_{c}": 0.0 for c in controls})
     for prompt, served in sample:
@@ -229,7 +228,7 @@ def check_sample(cfg: Dict[str, Any], seed: int, sample: List[tuple],
         lg = np.asarray(jax.device_get(ref(params, toks, pos)))
         out["logit_gap"] = max(out["logit_gap"], compare.logit_gap(lg, served))
         for c in controls:
-            lc = np.asarray(jax.device_get(decoder.position_logits(cfg, c)(params, toks, pos)))
+            lc = np.asarray(jax.device_get(family.position_logits(cfg, c)(params, toks, pos)))
             out[f"control_{c}"] = max(out[f"control_{c}"],
                                       compare.logit_gap(lg, lc.argmax(axis=-1)))
     return out

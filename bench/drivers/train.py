@@ -24,8 +24,7 @@ import jax
 import numpy as np
 
 from bench import compare, inputs
-from bench import model as bm
-from bench.harness import Cell, Check, GcPauses, Outcome, Run
+from bench.harness import Cell, Check, GcPauses, Outcome, Run, load_family
 from bench.reference import train as ref_train
 
 #: steps whose readings the checks compare (losses of all, the gradient of
@@ -131,9 +130,10 @@ def run(cell: Cell, run: Run) -> Outcome:
         async_checkpoint=True,
         opt=AdamWConfig(**opt),
     )
-    trainer = Trainer(bm.program_config(cfg, attn_impl=st.get("attn_impl", "auto")), tc)
-    bm.check_layout(cfg, trainer.model)
-    weights = [bm.make_weights(cfg, weight_seed)]
+    family = load_family(cell)
+    trainer = Trainer(family.program_config(cfg, attn_impl=st.get("attn_impl", "auto")), tc)
+    family.check_layout(cfg, trainer.model)
+    weights = [family.make_weights(cfg, weight_seed)]
 
     def init(_rng):
         # hand the benchmark's weights to the Trainer once; the donating
@@ -193,7 +193,7 @@ def run(cell: Cell, run: Run) -> Outcome:
     del trainer, step_fn, recording_step
     gc.collect()
 
-    checks, detail = check_steps(cfg, mix, seed, weight_seed, data, losses, readings)
+    checks, detail = check_steps(family, cfg, mix, seed, weight_seed, data, losses, readings)
     run.mark("reference check")
     run.note(f"checked leaves: grad {detail['_grad_leaf']}, change {detail['_change_leaf']}; "
              f"left out of change_gap: {detail['_left_out']}; loss_gap (not compared) "
@@ -206,8 +206,8 @@ def run(cell: Cell, run: Run) -> Outcome:
     counters = {
         "traced_steps": window.traced_steps,
         "traced_tokens_per_s": traced_rate,
-        "flops_per_token": train_flops_per_token(cfg, seq),
-        "attention_calls": attention_calls(cfg, rows, seq),
+        "flops_per_token": family.flops_per_token(cfg, seq),
+        "attention_calls": family.attention_calls(cfg, rows, seq),
     }
     return Outcome(
         setup_s=setup_s,
@@ -220,14 +220,14 @@ def run(cell: Cell, run: Run) -> Outcome:
     )
 
 
-def check_steps(cfg, mix, seed, weight_seed, data: List[np.ndarray], losses, readings
-                ) -> tuple:
+def check_steps(family, cfg, mix, seed, weight_seed, data: List[np.ndarray], losses,
+                readings) -> tuple:
     """Gaps of the program's first steps from the plain reference."""
     rows, seq = int(mix["global_batch"]), int(mix["seq_len"])
     want = [inputs.token_batch(seed, s, vocab=cfg["vocab_size"], seq_len=seq, rows=rows)
             for s in range(CHECKED_STEPS)]
     mismatch = sum(int(np.sum(a != b)) for a, b in zip(data, want, strict=True))
-    ref = ref_train.run(cfg, weight_seed, want, mix["optimizer"],
+    ref = ref_train.run(family, cfg, weight_seed, want, mix["optimizer"],
                         other_params=readings.pop("params"))
     prog = {
         "losses": [losses.get(s, float("nan")) for s in range(CHECKED_STEPS)],
@@ -239,27 +239,3 @@ def check_steps(cfg, mix, seed, weight_seed, data: List[np.ndarray], losses, rea
     checks["data_mismatch"] = float(mismatch)
     return checks, gaps
 
-
-def train_flops_per_token(cfg: Dict[str, Any], seq: int) -> float:
-    """Model FLOPs per trained token: 6 x matmul weights + causal attention.
-
-    Recomputation (remat) is not counted. The embedding lookup is not a
-    matmul; the output head is.
-    """
-    d, L = cfg["hidden_size"], cfg["num_hidden_layers"]
-    H, KV = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    hd = cfg.get("head_dim", d // H)
-    ff, V = cfg["intermediate_size"], cfg["vocab_size"]
-    per_layer = d * (H * hd) * 2 + d * (KV * hd) * 2 + 3 * d * ff
-    matmul_params = L * per_layer + d * V
-    # causal attention: QK^T and PV, 2 FLOPs per MAC, half the positions
-    attn = L * 2 * 2 * H * hd * seq / 2
-    return 6.0 * matmul_params + 3.0 * attn
-
-
-def attention_calls(cfg: Dict[str, Any], rows: int, seq: int) -> Dict[str, Any]:
-    """Shapes of one flash-attention call of the step, for the kernel counter."""
-    d, H = cfg["hidden_size"], cfg["num_attention_heads"]
-    return {"batch": rows, "heads": H, "kv_heads": cfg["num_key_value_heads"],
-            "q_len": seq, "kv_len": seq, "head_dim": cfg.get("head_dim", d // H),
-            "causal": True, "dtype_bytes": 2}

@@ -1,22 +1,27 @@
-"""A configuration file as the program runs it, and its weights from the seed.
+"""The dense decoder family: StableLM 2 and Qwen3, as their model cards define them.
 
-The configuration files use the published ``config.json`` key names. This
-module maps them onto the program's ``ModelConfig`` and makes the weights:
-random from the seed, on the device in one jitted call, in the parameter
-dtype, laid out as the program's tree. The plain reference reads the same
-tree (``bench.reference.decoder``); neither side takes weights the other
-made.
+A family is what the benchmark knows of one architecture; the harness finds
+it as ``families/<bench_family>.py`` by the configuration file's
+``bench_family`` key (``dense`` when the key is absent). It gives
+
+* the program mapping: ``program_config`` (the program's ``ModelConfig``
+  for a configuration file), ``make_weights`` (random weights from the
+  seed, on the device, in the parameter dtype, laid out as the program's
+  tree) and ``check_layout``;
+* the plain reference: ``loss_and_grad`` and ``position_logits``
+  (``bench.reference.decoder``, which reads the tree ``layout`` describes);
+* the counters: ``flops_per_token`` and ``attention_calls``.
+
+The configuration files use the published ``config.json`` key names.
 """
 from __future__ import annotations
 
-import math
 from typing import Any, Dict, Tuple
 
 import jax
-import jax.numpy as jnp
 
-#: the program's names for the dtypes a configuration can state
-DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}
+from bench import weights
+from bench.reference.decoder import loss_and_grad, position_logits  # noqa: F401
 
 
 def program_config(cfg: Dict[str, Any], *, attn_impl: str = "auto"):
@@ -91,59 +96,36 @@ def layout(cfg: Dict[str, Any]) -> Dict[str, Any]:
     return tree
 
 
-def _leaves(tree: Dict[str, Any], path: Tuple[str, ...] = ()):
-    for k in sorted(tree):
-        v = tree[k]
-        if isinstance(v, dict):
-            yield from _leaves(v, path + (k,))
-        else:
-            yield path + (k,), v
-
-
-def _set(tree: Dict[str, Any], path: Tuple[str, ...], value: Any) -> None:
-    for k in path[:-1]:
-        tree = tree.setdefault(k, {})
-    tree[path[-1]] = value
-
-
-def _leaf_value(key: jax.Array, name: str, shape: Tuple[int, ...]) -> jax.Array:
-    """Random f32 values for one leaf, by its role."""
-    z = jax.random.truncated_normal(key, -2.0, 2.0, shape, jnp.float32)
-    if name == "scale" or name.endswith("_norm"):
-        return 1.0 + 0.1 * z
-    if name == "table" or name == "unembed":
-        return 0.02 * z
-    if name in ("bias", "bq", "bk", "bv"):
-        return 0.02 * z
-    fan_in = shape[-2]
-    return z / math.sqrt(fan_in)
-
-
 def make_weights(cfg: Dict[str, Any], seed: int) -> Dict[str, Any]:
     """All weights from ``seed``, on the device, in one jitted call."""
-    dtype = DTYPES[cfg["param_dtype"]]
-    leaves = list(_leaves(layout(cfg)))
-
-    @jax.jit
-    def build(key):
-        out: Dict[str, Any] = {}
-        for i, (path, shape) in enumerate(leaves):
-            _set(out, path, _leaf_value(jax.random.fold_in(key, i), path[-1], shape).astype(dtype))
-        return out
-
-    return build(jax.random.key(seed))
+    return weights.random_tree(layout(cfg), cfg["param_dtype"], seed)
 
 
 def check_layout(cfg: Dict[str, Any], program_model) -> None:
     """Raise unless the program's own tree has this layout's shapes."""
-    want = layout(cfg)
-    got = jax.eval_shape(lambda r: program_model.init(r)[0], jax.random.key(0))
-    got_shapes = jax.tree.map(lambda s: tuple(s.shape), got)
-    if got_shapes != want:
-        raise ValueError(f"program parameter tree differs from the benchmark's layout:\n"
-                         f"program {got_shapes}\nbench {want}")
+    weights.check_tree(layout(cfg), program_model)
 
 
-def leaf_paths(tree: Dict[str, Any]):
-    """``[(path, leaf)]`` in sorted order, the order both sides compare in."""
-    return list(_leaves(tree))
+def flops_per_token(cfg: Dict[str, Any], seq: int) -> float:
+    """Model FLOPs per trained token: 6 x matmul weights + causal attention.
+
+    Recomputation (remat) is not counted. The embedding lookup is not a
+    matmul; the output head is.
+    """
+    d, L = cfg["hidden_size"], cfg["num_hidden_layers"]
+    H, KV = cfg["num_attention_heads"], cfg["num_key_value_heads"]
+    hd = cfg.get("head_dim", d // H)
+    ff, V = cfg["intermediate_size"], cfg["vocab_size"]
+    per_layer = d * (H * hd) * 2 + d * (KV * hd) * 2 + 3 * d * ff
+    matmul_params = L * per_layer + d * V
+    # causal attention: QK^T and PV, 2 FLOPs per MAC, half the positions
+    attn = L * 2 * 2 * H * hd * seq / 2
+    return 6.0 * matmul_params + 3.0 * attn
+
+
+def attention_calls(cfg: Dict[str, Any], rows: int, seq: int) -> Dict[str, Any]:
+    """Shapes of one flash-attention call of the step, for the kernel counter."""
+    d, H = cfg["hidden_size"], cfg["num_attention_heads"]
+    return {"batch": rows, "heads": H, "kv_heads": cfg["num_key_value_heads"],
+            "q_len": seq, "kv_len": seq, "head_dim": cfg.get("head_dim", d // H),
+            "causal": True, "dtype_bytes": 2}

@@ -4,6 +4,9 @@ A cell of ``BENCHMARK.json`` names a configuration and a traffic mix. The
 harness reads, each by its name:
 
 * ``configs/<config>.json``: the model configuration as it is run;
+* ``families/<family>.py``: what the benchmark knows of the configuration's
+  architecture, named by its ``bench_family`` key (``dense`` when absent):
+  the program mapping, the plain reference and the counters;
 * ``traffic/<traffic>.json``: the traffic mix, including the driver that
   plays it (``drivers/<driver>.py``);
 * ``workloads/<cell>.json``: what belongs to the cell alone (its offered
@@ -129,6 +132,11 @@ def find_cell(name: str, *, bench_dir: Path = BENCH_DIR, spec: Optional[dict] = 
 
 def load_driver(cell: Cell) -> ModuleType:
     return load_module(cell.bench_dir / "drivers" / f"{cell.driver}.py")
+
+
+def load_family(cell: Cell) -> ModuleType:
+    family = cell.config.get("bench_family", "dense")
+    return load_module(cell.bench_dir / "families" / f"{family}.py")
 
 
 def load_reader(cell: Cell, metric: str) -> Callable[["Observations"], Optional[float]]:

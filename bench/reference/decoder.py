@@ -7,9 +7,10 @@ bias, untied head; Qwen3: RMSNorm, per-head RMSNorm of q and k, GQA, tied
 head). Rotary embedding rotates the first ``partial_rotary_factor`` of each
 head's dims with the half-split convention of the published code.
 
-It reads the parameter tree that ``bench.model.layout`` describes. The
-``weight_dtype`` option rounds every matrix to a lower precision before use:
-that is the control, which must come out as not correct.
+It reads the parameter tree that ``layout`` of ``bench/families/dense.py``
+describes. The ``weight_dtype`` option rounds every matrix to a lower
+precision before use: that is the control, which must come out as not
+correct.
 """
 from __future__ import annotations
 

@@ -46,8 +46,7 @@ def main() -> None:
     from jax.experimental import topologies
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
-    from bench import model as bm
-    from bench.harness import find_cell
+    from bench.harness import find_cell, load_family
     from repro.kernels import ops
     from repro.models import build
     from repro.optim.adamw import AdamWConfig, adamw_init
@@ -60,7 +59,7 @@ def main() -> None:
     one = SingleDeviceSharding(topo.devices[0])
 
     train = find_cell("train.stablelm-1.6b.steady")
-    cfg = bm.program_config(train.config, attn_impl="pallas")
+    cfg = load_family(train).program_config(train.config, attn_impl="pallas")
     model = build(cfg)
     opt = AdamWConfig(**train.traffic["optimizer"])
     rows, seq = train.traffic["global_batch"], train.traffic["seq_len"]
@@ -100,7 +99,7 @@ def main() -> None:
         rules.uninstall()
 
     serve = find_cell("serve.qwen3-1.7b.agent")
-    scfg = bm.program_config(serve.config, attn_impl="pallas")
+    scfg = load_family(serve).program_config(serve.config, attn_impl="pallas")
     smodel = build(scfg)
     sp = placed(jax.eval_shape(lambda r: smodel.init(r)[0], jax.random.key(0)), one)
     new = serve.traffic["new_tokens"]

@@ -8,6 +8,7 @@ import numpy as np
 import bench_tiny
 from bench import inputs
 from bench.drivers import serve
+from bench.harness import BENCH_DIR, load_module
 
 
 def test_serve_cell_runs_and_is_correct(tmp_path: Path):
@@ -72,7 +73,8 @@ def test_control_fails_the_check():
     reqs = inputs.requests(bench_tiny.SERVE_MIX, rate=4.0, seconds=2.0, seed=4,
                            vocab=cfg["vocab_size"])
     sample = [(r.prompt, list(range(1, 17))) for r in reqs[:4]]
-    gaps = serve.check_sample(cfg, 4, sample, controls=("float8",))
+    dense = load_module(BENCH_DIR / "families" / "dense.py")
+    gaps = serve.check_sample(dense, cfg, 4, sample, controls=("float8",))
     assert gaps["control_float8"] > bench_tiny.SERVE_SETTINGS["limits"]["logit_gap"]
 
 

@@ -15,10 +15,83 @@ ROOT = bench_tiny.ROOT
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+#: widths: never in ``reduced`` (besides any key ending in _dim, _rank, _width
+#: or in _size other than vocab_size)
+WIDTHS = {"hidden_size", "intermediate_size", "moe_intermediate_size", "num_experts_per_tok"}
+EXPERT_COUNTS = ("n_routed_experts", "num_experts", "num_local_experts")
 
 
 def _line(s: str) -> bool:
     return 1 <= len(s) <= 200 and "\n" not in s and "\t" not in s
+
+
+def reduced_errors(reduced, cfg) -> list:
+    """What is wrong with a configuration's ``reduced`` against its file.
+
+    A width is never cut. Every key cut is stated with its published value
+    under ``published``, and only those; the file states its deployment.
+    What is left keeps the floors of a cut model: an eighth of the
+    vocabulary, 8 routed experts (or all, where fewer are published), and 4
+    layers after the leading dense ones.
+    """
+    errors = []
+    for k in reduced:
+        if (k in WIDTHS or k.endswith(("_dim", "_rank", "_width"))
+                or (k.endswith("_size") and k != "vocab_size")):
+            errors.append(f"{k} is a width")
+    published = cfg.get("published", {})
+    if set(published) != set(reduced):
+        errors.append(f"published {sorted(published)} is not reduced {sorted(reduced)}")
+    if not cfg.get("deployment"):
+        errors.append("no deployment stated")
+    for k in set(reduced) & set(published):
+        if k not in cfg or cfg[k] == published[k]:
+            errors.append(f"{k} is not cut from {published[k]}")
+    if "vocab_size" in published and 8 * cfg.get("vocab_size", 0) < published["vocab_size"]:
+        errors.append("less than an eighth of the vocabulary")
+    for k in EXPERT_COUNTS:
+        if k in published and cfg.get(k, 0) < min(8, published[k]):
+            errors.append(f"{k} under 8")
+    if "num_hidden_layers" in published:
+        leading = cfg.get("first_k_dense_replace", cfg.get("num_dense_layers", 0))
+        if cfg.get("num_hidden_layers", 0) - leading < 4:
+            errors.append("fewer than 4 layers after the leading dense ones")
+    return errors
+
+
+MOONLIGHT = {  # the published shape of moonshotai/Moonlight-16B-A3B, cut for one chip
+    "hidden_size": 2048, "intermediate_size": 11264, "moe_intermediate_size": 1408,
+    "kv_lora_rank": 512, "qk_nope_head_dim": 128, "qk_rope_head_dim": 64, "v_head_dim": 128,
+    "num_attention_heads": 16, "num_experts_per_tok": 6, "first_k_dense_replace": 1,
+    "num_hidden_layers": 5, "n_routed_experts": 8, "vocab_size": 20480,
+    "published": {"num_hidden_layers": 27, "n_routed_experts": 64, "vocab_size": 163840},
+    "deployment": "eight chips share each layer: 8 of its 64 experts and 20480 vocabulary rows",
+}
+MOONLIGHT_REDUCED = ["num_hidden_layers", "n_routed_experts", "vocab_size"]
+
+
+@pytest.mark.parametrize("reduced,changes,ok", [
+    (MOONLIGHT_REDUCED, {}, True),
+    (["num_hidden_layers", "vocab_size"], {"n_routed_experts": 64, "published": {
+        "num_hidden_layers": 27, "vocab_size": 163840}}, True),
+    # a width cut, stated under published as a cut must be, still fails
+    *[(MOONLIGHT_REDUCED + [k], {k: v, "published": dict(MOONLIGHT["published"],
+                                                         **{k: MOONLIGHT[k]})}, False)
+      for k, v in (("moe_intermediate_size", 704), ("kv_lora_rank", 256),
+                   ("qk_rope_head_dim", 32), ("num_experts_per_tok", 2))],
+    (MOONLIGHT_REDUCED, {"vocab_size": 20479}, False),
+    (MOONLIGHT_REDUCED, {"n_routed_experts": 7}, False),
+    (MOONLIGHT_REDUCED, {"num_hidden_layers": 4}, False),
+    (MOONLIGHT_REDUCED, {"published": {"num_hidden_layers": 27, "vocab_size": 163840}}, False),
+    (["num_hidden_layers", "vocab_size"], {}, False),
+    (MOONLIGHT_REDUCED, {"deployment": ""}, False),
+], ids=["moonlight_cut", "experts_whole", "expert_width", "latent_rank", "rope_head_dim",
+        "experts_per_token", "vocab_under_an_eighth", "experts_under_8",
+        "three_layers_after_dense", "reduced_key_not_published", "published_key_not_reduced",
+        "no_deployment"])
+def test_reduced_contract(reduced, changes, ok):
+    cfg = dict(MOONLIGHT, **changes)
+    assert (reduced_errors(reduced, cfg) == []) == ok, reduced_errors(reduced, cfg)
 
 
 def test_benchmark_json_keeps_the_contract():
@@ -40,7 +113,7 @@ def test_benchmark_json_keeps_the_contract():
         assert any(c["file"].startswith(p + "/") for p in spec["paths"])
         assert (ROOT / c["file"]).is_file()
         assert all(NAME.match(k) for k in c["reduced"]) and len(c["reduced"]) <= 16
-        assert not any(k.endswith(("_dim", "_rank", "_size")) for k in c["reduced"])
+        assert reduced_errors(c["reduced"], harness.load_json(ROOT / c["file"])) == [], c["name"]
     cells = set()
     for w in spec["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
@@ -113,6 +186,73 @@ def test_new_cell_and_metric_need_no_edit(tmp_path: Path):
     assert names == ["serve.requests_seen"]
     obs = harness.Observations(cell=cell, counters={"requests": 7})
     assert harness.load_reader(cell, "serve.requests_seen")(obs) == 7
+
+    # a model family, as files only: its module, and a configuration naming it
+    (tmp_path / "bench/families/plain_mlp.py").write_text(PLAIN_MLP)
+    train = bench_tiny.cell("train", tmp_path, seed=3_000_000_031)
+    train.bench_dir = tmp_path / "bench"
+    cfg = dict(train.config, bench_family="plain_mlp", mlp_width=96)
+    del cfg["intermediate_size"]  # a key the dense family cannot run without
+    train.config = cfg
+    assert Path(harness.load_family(train).__file__).parent == tmp_path / "bench/families"
+    line = bench_tiny.run(train)
+    assert line["correct"] is True, line["checks"]
+    assert line["metrics"]["train_tokens_per_s"]["value"] > 0
+
+
+#: a decoder whose MLP has no gate (``w_down(silu(w_up x))``), as a family
+PLAIN_MLP = """
+import dataclasses
+
+import jax
+
+from bench import weights
+from bench.harness import BENCH_DIR, load_module
+from bench.reference import decoder
+
+dense = load_module(BENCH_DIR / "families" / "dense.py")
+
+
+def _as_dense(cfg):
+    return dict(cfg, intermediate_size=cfg["mlp_width"])
+
+
+def program_config(cfg, *, attn_impl="auto"):
+    return dataclasses.replace(dense.program_config(_as_dense(cfg), attn_impl=attn_impl),
+                               glu=False)
+
+
+def layout(cfg):
+    tree = dense.layout(_as_dense(cfg))
+    del tree["seg0"]["u0"]["mlp"]["w_gate"]
+    return tree
+
+
+def make_weights(cfg, seed):
+    return weights.random_tree(layout(cfg), cfg["param_dtype"], seed)
+
+
+def check_layout(cfg, program_model):
+    weights.check_tree(layout(cfg), program_model)
+
+
+class Plain(decoder.Decoder):
+    def mlp(self, x, p):
+        return decoder._mm(jax.nn.silu(decoder._mm(x, self.w(p["w_up"]))), self.w(p["w_down"]))
+
+
+def loss_and_grad(cfg, weight_dtype=None):
+    return jax.jit(jax.value_and_grad(Plain(cfg, weight_dtype).loss))
+
+
+def flops_per_token(cfg, seq):
+    d, ff = cfg["hidden_size"], cfg["mlp_width"]
+    return dense.flops_per_token(_as_dense(cfg), seq) - 6.0 * cfg["num_hidden_layers"] * d * ff
+
+
+def attention_calls(cfg, rows, seq):
+    return dense.attention_calls(_as_dense(cfg), rows, seq)
+"""
 
 
 def test_unknown_names_are_errors():

@@ -88,7 +88,40 @@ def test_recorded_trace_of_the_flash_kernel():
     kernel = s.events(fa.matcher(call))
     assert len(kernel) == 3
     assert not s.events(fa.matcher(dict(call, q_len=128)))
+    # the op text names no kernel (its metadata is empty): the forward is told
+    # apart by its one output and its three operands, q first
+    assert all("kernel_metadata={}" in e.name for e in kernel)
+    assert not s.events(fa.matcher(dict(call, head_dim=128, v_head_dim=64)))
     assert sum(e.dur_ns for e in kernel) / 1e9 == pytest.approx(ops["_lambda_:tpu_custom_call"])
     assert s.breakdown()["device_ops"][0][0] == "_lambda_:tpu_custom_call"
     labels = [g[0] for g in s.idle_gaps()]
     assert "probe_step" in labels
+
+
+def _call(result, operands):
+    return ev(f"%fusion.3 = {result}{{3,2,1,0}} custom-call({operands}), "
+              f'custom_call_target="tpu_custom_call", operand_layout_constraints={{}}', 0, 10)
+
+
+@pytest.mark.parametrize("result,operands,forward", [
+    # the forward since the block plan: an s32 plan table, then q, k, v
+    ("bf16[1,16,8192,128]", "s32[3,8]{1,0} %plan, bf16[1,16,8192,192]{3,2,1,0} %q, "
+     "bf16[1,16,8192,192]{3,2,1,0} %k, bf16[1,16,8192,128]{3,2,1,0} %v", True),
+    # a backward's dq: same output shape, reads o and its gradient too
+    ("bf16[1,16,8192,128]", "bf16[1,16,8192,192]{3,2,1,0} %q, bf16[1,16,8192,192]{3,2,1,0} %k, "
+     "bf16[1,16,8192,128]{3,2,1,0} %v, bf16[1,16,8192,128]{3,2,1,0} %o, "
+     "bf16[1,16,8192,128]{3,2,1,0} %do, f32[1,16,8192]{2,1,0} %lse", False),
+    # a backward writing several gradients
+    ("(bf16[1,16,8192,128], bf16[1,16,8192,192])", "bf16[1,16,8192,192]{3,2,1,0} %q, "
+     "bf16[1,16,8192,192]{3,2,1,0} %k, bf16[1,16,8192,128]{3,2,1,0} %v", False),
+    # a call of the forward's output shape whose q is not 192 wide
+    ("bf16[1,16,8192,128]", "bf16[1,16,8192,128]{3,2,1,0} %a, "
+     "bf16[1,16,8192,128]{3,2,1,0} %b, bf16[1,16,8192,128]{3,2,1,0} %c", False),
+], ids=["forward", "dq", "tuple_out", "other_q_width"])
+def test_flash_matcher_tells_the_forward_apart(result, operands, forward):
+    from bench.harness import BENCH_DIR, load_module
+
+    fa = load_module(BENCH_DIR / "kernels" / "flash_attention.py")
+    mla = {"batch": 1, "heads": 16, "kv_heads": 16, "q_len": 8192, "kv_len": 8192,
+           "head_dim": 192, "v_head_dim": 128, "causal": True, "dtype_bytes": 2}
+    assert fa.matcher(mla)(_call(result, operands)) is forward
