@@ -92,6 +92,22 @@ def recurrentgemma_9b() -> ModelConfig:
         window=2048, act="gelu", logit_softcap=30.0)
 
 
+@register("granite-4.0-h-micro")
+def granite_4_h_micro() -> ModelConfig:
+    # Mamba-2 + GQA hybrid, period of 10 layers (5 mamba2, attn, 4 mamba2),
+    # NoPE, scaled residuals [hf:ibm-granite/granite-4.0-h-micro]
+    L = 40
+    pattern = tuple("attn" if i % 10 == 5 else "mamba2" for i in range(L))
+    return ModelConfig(
+        name="granite-4.0-h-micro", family="hybrid", num_layers=L, d_model=2048,
+        num_heads=32, num_kv_heads=8, head_dim=64, d_ff=8192,
+        vocab_size=100352, block_pattern=pattern, rope_fraction=0.0,
+        norm_eps=1e-5, tie_embeddings=True, attn_scale=0.015625,
+        embedding_multiplier=12.0, residual_multiplier=0.22, logits_scaling=8.0,
+        mamba_n_heads=64, mamba_d_head=64, mamba_d_state=128, mamba_chunk_size=256,
+        conv1d_width=4)
+
+
 @register("rwkv6-7b")
 def rwkv6_7b() -> ModelConfig:
     # Finch: data-dependent decay, attention-free [arXiv:2404.05892]

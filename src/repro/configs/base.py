@@ -37,6 +37,11 @@ class ModelConfig:
     glu: bool = True
     tie_embeddings: bool = False
     logit_softcap: float = 0.0
+    # Granite 4.0 scalars; at their defaults no operation is emitted
+    attn_scale: float = 0.0          # 0 ⇒ head_dim ** -0.5
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0  # on every residual branch
+    logits_scaling: float = 1.0      # logits are divided by it
 
     # MoE
     num_experts: int = 0
@@ -65,6 +70,13 @@ class ModelConfig:
     block_pattern: Tuple[str, ...] = ()   # per-layer kinds, len == num_layers
     lru_width: int = 0
     conv1d_width: int = 4
+
+    # Mamba-2 (SSD) mixer: d_inner = mamba_n_heads * mamba_d_head, one B/C
+    # group, a conv1d_width causal conv with a bias
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 64
+    mamba_d_state: int = 128
+    mamba_chunk_size: int = 256
 
     # ssm (RWKV6)
     rwkv_head_size: int = 64
@@ -174,12 +186,9 @@ def smoke_variant(cfg: ModelConfig) -> ModelConfig:
     if cfg.block_pattern:
         pattern = cfg.block_pattern[:n_layers]
         # keep at least one of each kind present in the original pattern
-        kinds = []
-        for k in cfg.block_pattern:
-            if k not in kinds:
-                kinds.append(k)
-        pattern = tuple((list(pattern) + kinds)[:n_layers]) if len(set(pattern)) < len(kinds) \
-            else pattern
+        missing = tuple(dict.fromkeys(k for k in cfg.block_pattern if k not in pattern))
+        if missing:
+            pattern = pattern[:n_layers - len(missing)] + missing
     else:
         pattern = ()
     changes = dict(
@@ -212,4 +221,7 @@ def smoke_variant(cfg: ModelConfig) -> ModelConfig:
                        frontend_dim=min(cfg.frontend_dim, 64) or 64)
     if cfg.window:
         changes.update(window=16)
+    if cfg.mamba_n_heads:
+        changes.update(mamba_n_heads=8, mamba_d_head=32, mamba_d_state=16,
+                       mamba_chunk_size=16)
     return replace(cfg, name=cfg.name + "-smoke", **changes)

@@ -20,7 +20,7 @@ from jax.sharding import PartitionSpec as P
 
 from . import ref as _ref
 
-__all__ = ["flash_attention", "wkv6", "rglru", "default_impl"]
+__all__ = ["flash_attention", "wkv6", "rglru", "ssd", "default_impl"]
 
 _IMPLS = ("auto", "pallas", "interpret", "ref", "dense")
 
@@ -178,3 +178,34 @@ def rglru(x, a, *, initial_state=None, impl: str = "auto",
     btw, bw = {"b": 0, "m": 2}, {"b": 0, "m": 1}
     return _per_shard(kernel, (x, a, *h0), (btw, btw) + (bw,) * len(h0),
                       ((3, btw), (2, bw)))
+
+
+# --------------------------------------------------------------------------
+# Mamba-2 SSD
+# --------------------------------------------------------------------------
+
+def ssd(x, dt, A, B, C, *, chunk: int = 256, initial_state=None,
+        impl: str = "auto") -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Mamba-2 selective scan, one B/C group shared by every head.
+
+    x:(Bt,H,T,P) dt:(Bt,H,T) A:(H,) B,C:(Bt,T,N) initial_state:(Bt,H,P,N)
+    → (y (Bt,H,T,P), final state (Bt,H,P,N) float32); see ``ref.ssd_ref``.
+    """
+    impl = _resolve(impl)
+    if impl == "dense":
+        return _ref.ssd_ref(x, dt, A, B, C, initial_state=initial_state)
+    if impl == "ref":
+        return _ref.ssd_chunked_ref(x, dt, A, B, C, chunk=chunk,
+                                    initial_state=initial_state)
+    from .ssd import ssd_pallas
+
+    Bt, H, _, P = x.shape
+    h0 = initial_state if initial_state is not None else \
+        jnp.zeros((Bt, H, P, B.shape[-1]), jnp.float32)
+
+    def kernel(x, dt, A, B, C, h0):
+        return ssd_pallas(x, dt, A, B, C, h0, chunk, impl == "interpret")
+
+    bh, b = {"b": 0, "m": 1}, {"b": 0}
+    return _per_shard(kernel, (x, dt, A, B, C, h0), (bh, bh, {"m": 0}, b, b, bh),
+                      ((4, bh), (4, bh)))

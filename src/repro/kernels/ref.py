@@ -14,7 +14,8 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["flash_attention_ref", "flash_attention_dense_ref", "wkv6_ref",
-           "wkv6_chunked_ref", "rglru_ref", "rglru_scan_ref"]
+           "wkv6_chunked_ref", "rglru_ref", "rglru_scan_ref", "ssd_ref",
+           "ssd_chunked_ref"]
 
 _NEG_INF = -1e30
 
@@ -242,3 +243,88 @@ def rglru_scan_ref(x, a, *, initial_state=None) -> Tuple[jnp.ndarray, jnp.ndarra
     if initial_state is not None:
         h = h + A * initial_state.astype(jnp.float32)[:, None, :]
     return h.astype(x.dtype), h[:, -1].astype(jnp.float32)
+
+
+# ===========================================================================
+# Mamba-2 SSD: selective state-space scan, scalar decay per head
+# ===========================================================================
+
+def ssd_ref(x, dt, A, B, C, *, initial_state=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Sequential oracle. Shapes (one group of B/C shared by every head):
+      x: (Bt, H, T, P);  dt: (Bt, H, T) step sizes > 0;  A: (H,) < 0;
+      B, C: (Bt, T, N);  initial_state: (Bt, H, P, N).
+    Per head, with h ∈ R^{P×N}:
+      h_t = exp(dt_t A) h_{t-1} + dt_t x_t ⊗ B_t;   y_t = h_t C_t
+    Returns (y (Bt,H,T,P) in x's dtype, final state (Bt,H,P,N) float32).
+    """
+    Bt, H, T, P = x.shape
+    N = B.shape[-1]
+    xf, dtf, Bf, Cf = (a.astype(jnp.float32) for a in (x, dt, B, C))
+    Af = A.astype(jnp.float32)
+    h0 = (jnp.zeros((Bt, H, P, N), jnp.float32) if initial_state is None
+          else initial_state.astype(jnp.float32))
+
+    def step(h, inp):
+        xt, dtt, bt, ct = inp        # (Bt,H,P), (Bt,H), (Bt,N), (Bt,N)
+        h = jnp.exp(dtt * Af)[..., None, None] * h \
+            + (dtt[..., None] * xt)[..., None] * bt[:, None, None, :]
+        return h, jnp.einsum("bhpn,bn->bhp", h, ct)
+
+    h, ys = jax.lax.scan(step, h0, (jnp.moveaxis(xf, 2, 0), jnp.moveaxis(dtf, 2, 0),
+                                    jnp.moveaxis(Bf, 1, 0), jnp.moveaxis(Cf, 1, 0)))
+    return jnp.moveaxis(ys, 0, 2).astype(x.dtype), h
+
+
+def _pad_time(x, dt, B, C, chunk):
+    """Pad T to a chunk multiple with dt = 0 steps: they neither decay nor
+    add to the state, and their outputs are sliced off."""
+    pad = (-x.shape[2]) % chunk
+    if pad:
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        dt = jnp.pad(dt, ((0, 0), (0, 0), (0, pad)))
+        B = jnp.pad(B, ((0, 0), (0, pad), (0, 0)))
+        C = jnp.pad(C, ((0, 0), (0, pad), (0, 0)))
+    return x, dt, B, C
+
+
+def ssd_chunked_ref(x, dt, A, B, C, *, chunk: int = 256, initial_state=None
+                    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Chunked dual form (what the TPU kernel computes), in float32 jnp.
+
+    Per chunk of Q steps, with cum the in-chunk prefix sum of dt·A:
+      y    = (L ∘ C Bᵀ ∘ dt) x + exp(cum) ⊙ (C h₀ᵀ),  L_ij = exp(cum_i − cum_j), i ≥ j
+      h_Q  = exp(cum_Q) h₀ + Σ_j exp(cum_Q − cum_j) dt_j x_j ⊗ B_j
+    The chunk's state is carried to the next in order. Each chunk step is
+    rematerialized under differentiation, so its backward keeps one state
+    per chunk, not the (Q, Q) decay matrices of every chunk.
+    """
+    Bt, H, T, P = x.shape
+    N = B.shape[-1]
+    xp, dtp, Bp, Cp = _pad_time(*(a.astype(jnp.float32) for a in (x, dt, B, C)), chunk)
+    n = xp.shape[2] // chunk
+    Af = A.astype(jnp.float32)
+    h0 = (jnp.zeros((Bt, H, P, N), jnp.float32) if initial_state is None
+          else initial_state.astype(jnp.float32))
+    causal = jnp.tril(jnp.ones((chunk, chunk), bool))
+
+    @jax.checkpoint
+    def chunk_step(h, inp):
+        xc, dtc, bc, cc = inp            # (Bt,H,Q,P), (Bt,H,Q), (Bt,Q,N), (Bt,Q,N)
+        cum = jnp.cumsum(dtc * Af[None, :, None], axis=-1)             # (Bt,H,Q)
+        seg = jnp.where(causal, cum[..., :, None] - cum[..., None, :], -jnp.inf)
+        g = jnp.einsum("bin,bjn->bij", cc, bc)                          # (Bt,Q,Q)
+        m = jnp.exp(seg) * g[:, None] * dtc[..., None, :]               # (Bt,H,Q,Q)
+        y = jnp.einsum("bhij,bhjp->bhip", m, xc) \
+            + jnp.exp(cum)[..., None] * jnp.einsum("bin,bhpn->bhip", cc, h)
+        last = cum[..., -1:]                                            # (Bt,H,1)
+        w = jnp.exp(last - cum) * dtc                                   # (Bt,H,Q)
+        h = jnp.exp(last)[..., None] * h + jnp.einsum("bhj,bhjp,bjn->bhpn", w, xc, bc)
+        return h, y
+
+    xs = (jnp.moveaxis(xp.reshape(Bt, H, n, chunk, P), 2, 0),
+          jnp.moveaxis(dtp.reshape(Bt, H, n, chunk), 2, 0),
+          jnp.moveaxis(Bp.reshape(Bt, n, chunk, N), 1, 0),
+          jnp.moveaxis(Cp.reshape(Bt, n, chunk, N), 1, 0))
+    h, ys = jax.lax.scan(chunk_step, h0, xs)
+    y = jnp.moveaxis(ys, 0, 2).reshape(Bt, H, n * chunk, P)[:, :, :T]
+    return y.astype(x.dtype), h

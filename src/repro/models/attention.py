@@ -84,6 +84,7 @@ def gqa_attention(x: jax.Array, p: Dict[str, Any], cfg, *,
        - else:           full-sequence self attention."""
     B, S, d = x.shape
     H, hd = cfg.num_heads, cfg.head_dim
+    scale = cfg.attn_scale or None   # None: the kernel's head_dim ** -0.5
 
     if cross_kv is not None:
         k, v = cross_kv  # (B, Hkv, Ssrc, hd) — precomputed, already roped/plain
@@ -91,7 +92,7 @@ def gqa_attention(x: jax.Array, p: Dict[str, Any], cfg, *,
         if cfg.qk_norm:
             q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
         q = jnp.moveaxis(q, 1, 2)
-        out = ops.flash_attention(q, k, v, causal=False, impl=cfg.attn_impl)
+        out = ops.flash_attention(q, k, v, causal=False, scale=scale, impl=cfg.attn_impl)
         out = jnp.moveaxis(out, 1, 2).reshape(B, S, H * hd)
         return dense(out, p["wo"]), None
 
@@ -100,7 +101,7 @@ def gqa_attention(x: jax.Array, p: Dict[str, Any], cfg, *,
 
     if cache is None:
         out = ops.flash_attention(q, k, v, causal=causal, window=window,
-                                  impl=cfg.attn_impl)
+                                  scale=scale, impl=cfg.attn_impl)
         out = jnp.moveaxis(out, 1, 2).reshape(B, S, H * hd)
         return dense(out, p["wo"]), None
 
@@ -126,7 +127,7 @@ def gqa_attention(x: jax.Array, p: Dict[str, Any], cfg, *,
     qf = q.astype(jnp.float32)
     kf = jnp.repeat(kq, g, axis=1).astype(jnp.float32)
     vf = jnp.repeat(vq, g, axis=1).astype(jnp.float32)
-    logits = jnp.einsum("bhqd,bhkd->bhqk", qf, kf) * (hd ** -0.5)
+    logits = jnp.einsum("bhqd,bhkd->bhqk", qf, kf) * (scale or hd ** -0.5)
     idx = jnp.arange(Sc)
     if window and window > 0 and Sc == window:
         ages = jnp.mod(pos[:, None] - idx[None, :], window)  # (B, Sc)

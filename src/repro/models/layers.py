@@ -14,7 +14,7 @@ import jax.numpy as jnp
 
 __all__ = ["ParamStore", "rmsnorm", "layernorm", "apply_norm", "norm_param",
            "dense", "rope", "glu_mlp", "init_glu_mlp", "shard_activation",
-           "set_activation_sharder", "softcap", "DTYPES"]
+           "set_activation_sharder", "softcap", "causal_conv1d", "DTYPES"]
 
 DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16, "float16": jnp.float16}
 
@@ -143,6 +143,22 @@ def dense(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None) -> jax.Arra
     if b is not None:
         out = out + b.astype(out.dtype)
     return out
+
+
+def causal_conv1d(x: jax.Array, weight: jax.Array, bias: jax.Array,
+                  tail: Optional[jax.Array]) -> Tuple[jax.Array, jax.Array]:
+    """Depthwise causal conv. x: (B,T,W); weight: (K,W); tail: the last K-1
+    inputs before x, zeros when None. Returns (y, new_tail)."""
+    B, T, W = x.shape
+    K = weight.shape[0]
+    if tail is None:
+        tail = jnp.zeros((B, K - 1, W), x.dtype)
+    xp = jnp.concatenate([tail, x], axis=1)            # (B, T+K-1, W)
+    y = jnp.zeros((B, T, W), jnp.float32)
+    for i in range(K):  # K is tiny (4): unrolled taps, no conv primitive needed
+        y = y + xp[:, i: i + T, :].astype(jnp.float32) * weight[i].astype(jnp.float32)
+    y = (y + bias.astype(jnp.float32)).astype(x.dtype)
+    return y, xp[:, T:, :]
 
 
 def softcap(logits: jax.Array, cap: float) -> jax.Array:

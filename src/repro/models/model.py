@@ -89,7 +89,10 @@ def build(cfg: ModelConfig) -> Model:
 
     # -- embedding helpers -----------------------------------------------------
     def embed_tokens(params, tokens):
-        return params["embed"]["table"][tokens].astype(cdtype)
+        h = params["embed"]["table"][tokens].astype(cdtype)
+        if cfg.embedding_multiplier != 1.0:
+            h = h * cfg.embedding_multiplier
+        return h
 
     def unembed(params, h):
         h = apply_norm(h, params["final_norm"], cfg.norm, cfg.norm_eps)
@@ -99,6 +102,8 @@ def build(cfg: ModelConfig) -> Model:
         else:
             logits = jnp.einsum("...d,dv->...v", h, params["unembed"],
                                 preferred_element_type=jnp.float32)
+        if cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
         logits = softcap(logits, cfg.logit_softcap)
         if vpad != cfg.vocab_size:  # mask pad-vocab slots out of softmax
             logits = jnp.where(jnp.arange(vpad) < cfg.vocab_size, logits,

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops
-from .layers import ParamStore, dense, shard_activation
+from .layers import ParamStore, causal_conv1d, dense, shard_activation
 
 __all__ = ["init_recurrent_block", "recurrent_block", "init_rglru_state"]
 
@@ -47,21 +47,6 @@ def init_rglru_state(cfg, batch: int, dtype) -> Dict[str, Any]:
             "conv": jnp.zeros((batch, cfg.conv1d_width - 1, w), dtype)}
 
 
-def _causal_conv1d(x: jax.Array, weight: jax.Array, bias: jax.Array,
-                   tail: Optional[jax.Array]) -> Tuple[jax.Array, jax.Array]:
-    """Depthwise causal conv. x: (B,T,W); weight: (K,W). Returns (y, new_tail)."""
-    B, T, W = x.shape
-    K = weight.shape[0]
-    if tail is None:
-        tail = jnp.zeros((B, K - 1, W), x.dtype)
-    xp = jnp.concatenate([tail, x], axis=1)            # (B, T+K-1, W)
-    y = jnp.zeros((B, T, W), jnp.float32)
-    for i in range(K):  # K is tiny (4): unrolled taps, no conv primitive needed
-        y = y + xp[:, i: i + T, :].astype(jnp.float32) * weight[i].astype(jnp.float32)
-    y = (y + bias.astype(jnp.float32)).astype(x.dtype)
-    return y, xp[:, T:, :]
-
-
 def recurrent_block(x: jax.Array, p: Dict[str, Any], cfg, *,
                     state: Optional[Dict[str, Any]] = None
                     ) -> Tuple[jax.Array, Optional[Dict[str, Any]]]:
@@ -70,7 +55,7 @@ def recurrent_block(x: jax.Array, p: Dict[str, Any], cfg, *,
     xi = dense(x, p["w_in_rec"])
     xi = shard_activation(xi, "lru_bsw")
     tail = state["conv"] if state is not None else None
-    xi, new_tail = _causal_conv1d(xi, p["conv_w"], p["conv_b"], tail)
+    xi, new_tail = causal_conv1d(xi, p["conv_w"], p["conv_b"], tail)
 
     r = jax.nn.sigmoid(dense(xi, p["w_a"], p["b_a"]).astype(jnp.float32))
     i = jax.nn.sigmoid(dense(xi, p["w_x"], p["b_x"]).astype(jnp.float32))

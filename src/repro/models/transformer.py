@@ -6,6 +6,7 @@ Layer kinds:
   rec   : Griffin recurrent block (conv1d + RG-LRU) + GLU MLP
   attn  : alias of dense used inside hybrid patterns (local window applies)
   rwkv  : RWKV6 time-mix + channel-mix
+  mamba2: Mamba-2 mixer (conv1d + SSD scan, gated RMSNorm) + GLU MLP
   enc   : bidirectional encoder self-attention + MLP
   xattn : decoder self-attention + cross-attention + MLP (enc-dec)
 
@@ -28,6 +29,7 @@ from .attention import (gqa_attention, init_gqa, init_gqa_cache, init_mla,
                         init_mla_cache, mla_attention)
 from .layers import (ParamStore, apply_norm, dense, glu_mlp, init_glu_mlp,
                      norm_param, shard_activation)
+from .mamba2 import init_mamba2, init_mamba2_state, mamba2_mixer
 from .moe import init_moe, moe_block
 from .rglru import init_recurrent_block, init_rglru_state, recurrent_block
 from .rwkv import (init_rwkv_layer, init_rwkv_state, rwkv_channel_mix,
@@ -91,9 +93,12 @@ def init_layer(store: ParamStore, cfg, kind: str) -> None:
         norm_param(store, "ln2", cfg.d_model, cfg.norm)
         init_rwkv_layer(store, "rwkv", cfg)
         return
-    if kind == "rec":
+    if kind in ("rec", "mamba2"):
         norm_param(store, "ln1", cfg.d_model, cfg.norm)
-        init_recurrent_block(store, "rec", cfg)
+        if kind == "rec":
+            init_recurrent_block(store, "rec", cfg)
+        else:
+            init_mamba2(store, "mamba", cfg)
         norm_param(store, "ln2", cfg.d_model, cfg.norm)
         init_glu_mlp(store, "mlp", cfg.d_model, cfg.d_ff, cfg.glu)
         return
@@ -119,6 +124,8 @@ def init_layer_cache(cfg, kind: str, batch: int, seq_len: int, dtype,
         return init_rwkv_state(cfg, batch, dtype)
     if kind == "rec":
         return init_rglru_state(cfg, batch, dtype)
+    if kind == "mamba2":
+        return init_mamba2_state(cfg, batch, dtype)
     size = min(cfg.window, seq_len) if (cfg.window and kind == "attn") else seq_len
     cache = init_mla_cache(cfg, batch, size, dtype) if cfg.mla \
         else init_gqa_cache(cfg, batch, size, dtype)
@@ -169,6 +176,13 @@ def _prefill_cache_from_full(h_in, lp, cfg, kind, positions, seq_len):
     return {"k": k, "v": v, "pos": pos_vec}
 
 
+def _residual(h: jax.Array, branch: jax.Array, cfg) -> jax.Array:
+    """h + branch, the branch scaled by ``residual_multiplier`` unless it is 1."""
+    if cfg.residual_multiplier != 1.0:
+        branch = branch * cfg.residual_multiplier
+    return h + branch
+
+
 def apply_layer(h: jax.Array, lp: Dict[str, Any], cfg, kind: str, *,
                 positions: jax.Array, mode: str,
                 cache: Optional[Any] = None,
@@ -177,17 +191,17 @@ def apply_layer(h: jax.Array, lp: Dict[str, Any], cfg, kind: str, *,
     aux = jnp.zeros((), jnp.float32)
     B = h.shape[0]
     seq_len = h.shape[1]
-    if mode == "prefill" and cache is None and kind in ("rwkv", "rec"):
+    if mode == "prefill" and cache is None and kind in ("rwkv", "rec", "mamba2"):
         cache = init_layer_cache(cfg, kind, B, seq_len, h.dtype)
 
     if kind == "rwkv":
         x1 = apply_norm(h, lp["ln1"], cfg.norm, cfg.norm_eps)
         tm_out, st = rwkv_time_mix(x1, lp["rwkv"], cfg,
                                    state=cache if mode != "train" else None)
-        h = h + tm_out
+        h = _residual(h, tm_out, cfg)
         x2 = apply_norm(h, lp["ln2"], cfg.norm, cfg.norm_eps)
         cm_out, st2 = rwkv_channel_mix(x2, lp["rwkv"], cfg, state=st)
-        h = h + cm_out
+        h = _residual(h, cm_out, cfg)
         if mode == "train":
             return h, None, aux
         if mode == "prefill":
@@ -196,17 +210,20 @@ def apply_layer(h: jax.Array, lp: Dict[str, Any], cfg, kind: str, *,
             st2["cm_prev"] = x2[:, -1, :]
         return h, st2, aux
 
-    if kind == "rec":
+    if kind in ("rec", "mamba2"):
         x1 = apply_norm(h, lp["ln1"], cfg.norm, cfg.norm_eps)
-        rec_out, st = recurrent_block(x1, lp["rec"], cfg,
-                                      state=cache if mode != "train" else None)
-        h = h + rec_out
+        state = cache if mode != "train" else None
+        if kind == "rec":
+            mix_out, st = recurrent_block(x1, lp["rec"], cfg, state=state)
+        else:
+            mix_out, st = mamba2_mixer(x1, lp["mamba"], cfg, state=state)
+        h = _residual(h, mix_out, cfg)
         x2 = apply_norm(h, lp["ln2"], cfg.norm, cfg.norm_eps)
-        h = h + glu_mlp(x2, lp["mlp"], cfg.act, cfg.glu)
+        h = _residual(h, glu_mlp(x2, lp["mlp"], cfg.act, cfg.glu), cfg)
         if mode == "train":
             return h, None, aux
         if mode == "prefill" and st is None:
-            st = init_rglru_state(cfg, B, h.dtype)
+            st = init_layer_cache(cfg, kind, B, seq_len, h.dtype)
         return h, st, aux
 
     # attention-bearing kinds ------------------------------------------------
@@ -216,7 +233,7 @@ def apply_layer(h: jax.Array, lp: Dict[str, Any], cfg, kind: str, *,
         cache=(cache["self"] if kind == "xattn" else cache) if cache is not None
         else None,
         mode=mode)
-    h = h + attn_out
+    h = _residual(h, attn_out, cfg)
     if mode == "prefill":
         new_cache = _prefill_cache_from_full(x1, lp, cfg, kind, positions, seq_len)
 
@@ -234,7 +251,7 @@ def apply_layer(h: jax.Array, lp: Dict[str, Any], cfg, kind: str, *,
             ck, cv = jnp.moveaxis(ck, 1, 2), jnp.moveaxis(cv, 1, 2)
         x_out, _ = gqa_attention(xx, lp["xattn"], cfg, positions=positions,
                                  cross_kv=(ck, cv))
-        h = h + x_out
+        h = _residual(h, x_out, cfg)
         if mode == "prefill":
             new_cache = {"self": new_cache, "cross_k": ck, "cross_v": cv}
         elif mode == "decode":
@@ -245,7 +262,7 @@ def apply_layer(h: jax.Array, lp: Dict[str, Any], cfg, kind: str, *,
         ffn_out, aux = moe_block(x2, lp["moe"], cfg)
     else:
         ffn_out = glu_mlp(x2, lp["mlp"], cfg.act, cfg.glu)
-    h = h + ffn_out
+    h = _residual(h, ffn_out, cfg)
     h = shard_activation(h, "tokens_bsd")
     return h, (new_cache if mode != "train" else None), aux
 

@@ -64,6 +64,12 @@ class ShardingRules:
             "experts": self.model_axis if self.opt.expert_parallel else fsdp_axis,
             "lru": self.model_axis,
             "lora": None,
+            # Mamba-2: heads and the inner channels split on the model axis;
+            # the fused in_proj output and the conv channels (x, B, C) whole
+            "ssm_heads": self.model_axis,
+            "ssm_inner": self.model_axis,
+            "ssm_in": None,
+            "ssm_conv": None,
         }
         for k, v in self.opt.logical_overrides:
             self.table[k] = v
@@ -223,7 +229,7 @@ class ShardingRules:
             elif key in ("cross_k", "cross_v"):  # (L, B, KV, Ssrc, hd)
                 spec = P(None, self.dp, m if self.cache_on_heads else None,
                          None, None)
-            elif key == "wkv":             # (L, B, H, K, V)
+            elif key in ("wkv", "ssm"):    # (L, B, H, K, V) / (L, B, H, P, N)
                 spec = P(None, self.dp, m, None, None)
             elif key in ("h",):            # (L, B, W)
                 spec = P(None, self.dp, m)

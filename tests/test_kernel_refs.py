@@ -148,3 +148,39 @@ def test_rglru_scan_matches_sequential_grads():
     gx_p, ga_p = jax.grad(loss(ref.rglru_scan_ref), argnums=(0, 1))(x, a)
     close(gx_p, gx_s, rtol=1e-4, atol=1e-4)
     close(ga_p, ga_s, rtol=1e-4, atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# Mamba-2 SSD: chunked dual form vs the sequential recurrence
+# ---------------------------------------------------------------------------
+def _ssd_inputs(Bt=2, H=3, T=50, P=8, N=6):
+    r = np.random.default_rng(11)
+    f = lambda *s: jnp.asarray(r.normal(size=s), jnp.float32)
+    dt = jnp.asarray(np.log1p(np.exp(r.normal(size=(Bt, H, T)) - 2.0)), jnp.float32)
+    A = -jnp.asarray(r.uniform(1.0, 16.0, size=H), jnp.float32)
+    return f(Bt, H, T, P), dt, A, f(Bt, T, N), f(Bt, T, N), f(Bt, H, P, N)
+
+
+@pytest.mark.parametrize("chunk", [8, 16, 64])
+@pytest.mark.parametrize("with_state", [False, True])
+def test_ssd_chunked_matches_sequential(chunk, with_state):
+    x, dt, A, B, C, h0 = _ssd_inputs()
+    h0 = h0 if with_state else None
+    y, h = ref.ssd_chunked_ref(x, dt, A, B, C, chunk=chunk, initial_state=h0)
+    ys, hs = ref.ssd_ref(x, dt, A, B, C, initial_state=h0)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(ys), rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(h), np.asarray(hs), rtol=1e-4, atol=1e-4)
+
+
+def test_ssd_chunked_matches_sequential_grads():
+    args = _ssd_inputs(T=37)
+
+    def loss(fn):
+        return lambda *a: sum(jnp.sum(jnp.cos(o)) for o in fn(*a[:5], initial_state=a[5]))
+
+    g1 = jax.grad(loss(lambda *a, **k: ref.ssd_chunked_ref(*a, chunk=16, **k)),
+                  argnums=range(6))(*args)
+    g2 = jax.grad(loss(ref.ssd_ref), argnums=range(6))(*args)
+    for a, b in zip(g1, g2, strict=True):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
+                                   atol=1e-5 * float(jnp.max(jnp.abs(b))))

@@ -298,14 +298,93 @@ def test_rglru_state_chaining_equals_one_shot():
                                rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("op", ["flash_attention", "wkv6", "rglru"])
+# ---------------------------------------------------------------------------
+# Mamba-2 SSD
+# ---------------------------------------------------------------------------
+def _ssd_args(Bt, H, T, P, N, dtype=jnp.float32):
+    """x, dt, A, B, C as the mixer makes them: dt = softplus(.) around its
+    initial range, A = -U[1, 16]."""
+    dt = jnp.asarray(np.log1p(np.exp(RNG.normal(size=(Bt, H, T)) - 3.0)), jnp.float32)
+    A = -jnp.asarray(RNG.uniform(1.0, 16.0, size=H), jnp.float32)
+    return (rand((Bt, H, T, P), dtype), dt, A, rand((Bt, T, N), dtype), rand((Bt, T, N), dtype))
+
+
+SSD_CASES = [
+    # (Bt, H, T, P, N, chunk, with_state, dtype)
+    (1, 2, 32, 16, 8, 16, False, jnp.float32),     # two whole chunks
+    (2, 4, 40, 16, 8, 16, True, jnp.float32),      # ragged T, carried state
+    (1, 16, 48, 8, 16, 16, True, jnp.float32),     # two steps of 8 heads
+    (1, 3, 20, 16, 8, 32, True, jnp.float32),      # T under one chunk, odd heads
+    (2, 4, 64, 32, 16, 32, True, jnp.bfloat16),    # bf16 operands on the MXU
+]
+
+
+@pytest.mark.parametrize("case", SSD_CASES)
+def test_ssd_kernel_matches_references(case):
+    """Kernel (interpret) against the blocked jnp form and the sequential
+    recurrence. float32: the three differ by reassociation only (2e-4,
+    values of order 10). bfloat16: the kernel rounds C·Bᵀ's operands, the
+    masked decay matrix and the state to bf16 (8 bits) before its matmuls,
+    which the float32 references do not: a few % of the output's scale."""
+    Bt, H, T, P, N, chunk, with_state, dtype = case
+    x, dt, A, B, C = _ssd_args(Bt, H, T, P, N, dtype)
+    h0 = rand((Bt, H, P, N)) if with_state else None
+    before = metrics().counter("repro_kernel_ssd_chunks_total", kind="visited").value
+    y, h = ops.ssd(x, dt, A, B, C, chunk=chunk, initial_state=h0, impl="interpret")
+    visited = metrics().counter("repro_kernel_ssd_chunks_total", kind="visited").value - before
+    assert visited == Bt * H * -(-T // chunk)
+    y_seq, h_seq = ref.ssd_ref(x, dt, A, B, C, initial_state=h0)
+    y_blk, h_blk = ref.ssd_chunked_ref(x, dt, A, B, C, chunk=chunk, initial_state=h0)
+    assert y.shape == (Bt, H, T, P) and y.dtype == dtype and h.dtype == jnp.float32
+    f32 = lambda a: np.asarray(a, np.float32)
+    tol = 2e-4 if dtype == jnp.float32 else 5e-2 * float(jnp.max(jnp.abs(f32(y_seq))))
+    for got, want in ((y, y_seq), (h, h_seq), (y_blk, y_seq), (h_blk, h_seq)):
+        np.testing.assert_allclose(f32(got), f32(want), rtol=2e-4, atol=tol)
+
+
+def test_ssd_grad_matches_recurrence_grad():
+    """The custom VJP (float32 chunked recompute) against autodiff of the
+    sequential recurrence, for every input, the state's gradient included."""
+    x, dt, A, B, C = _ssd_args(2, 4, 40, 16, 8)
+    h0 = rand((2, 4, 16, 8))
+    w = rand((2, 4, 40, 16))
+
+    def loss(impl):
+        def f(x, dt, A, B, C, h0):
+            y, h = ops.ssd(x, dt, A, B, C, chunk=16, initial_state=h0, impl=impl)
+            return jnp.sum(w * y) + jnp.sum(jnp.sin(h))
+        return f
+
+    got = jax.grad(loss("interpret"), argnums=range(6))(x, dt, A, B, C, h0)
+    want = jax.grad(loss("dense"), argnums=range(6))(x, dt, A, B, C, h0)
+    for g, v in zip(got, want, strict=True):
+        scale = float(jnp.max(jnp.abs(v)))
+        np.testing.assert_allclose(np.asarray(g), np.asarray(v), rtol=1e-4, atol=1e-5 * scale)
+
+
+def test_ssd_state_chaining_equals_one_shot():
+    """Two calls, the second from the first's final state, equal one call:
+    what prefill and a later prefill of the continuation rely on."""
+    x, dt, A, B, C = _ssd_args(1, 4, 48, 16, 8)
+    y, h = ops.ssd(x, dt, A, B, C, chunk=16, impl="interpret")
+    y1, h1 = ops.ssd(x[:, :, :20], dt[:, :, :20], A, B[:, :20], C[:, :20], chunk=16,
+                     impl="interpret")
+    y2, h2 = ops.ssd(x[:, :, 20:], dt[:, :, 20:], A, B[:, 20:], C[:, 20:], chunk=16,
+                     initial_state=h1, impl="interpret")
+    np.testing.assert_allclose(np.asarray(jnp.concatenate([y1, y2], 2)), np.asarray(y),
+                               rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(np.asarray(h2), np.asarray(h), rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("op", ["flash_attention", "wkv6", "rglru", "ssd"])
 def test_pallas_impl_refuses_non_tpu_backend(op, monkeypatch):
     """impl="pallas" means the compiled Mosaic kernel, never a silent
     fallback to the interpreter; off-TPU it must raise."""
     x = rand((1, 2, 16, 16))
     args = {"flash_attention": (x, x, x),
             "wkv6": (x, x, x, decays(x.shape), rand((2, 16))),
-            "rglru": (x[0], decays(x[0].shape))}[op]
+            "rglru": (x[0], decays(x[0].shape)),
+            "ssd": _ssd_args(1, 2, 16, 16, 8)}[op]
     monkeypatch.setattr(ops, "_on_tpu", lambda: False)
     with pytest.raises(RuntimeError, match="impl='interpret'"):
         getattr(ops, op)(*args, impl="pallas")
@@ -318,12 +397,15 @@ def _op_args(op):
         shape = (2, 4, 32, 16)
         return (rand(shape), rand(shape), rand(shape), decays(shape), rand((4, 16))), {
             "initial_state": rand((2, 4, 16, 16))}
+    if op == "ssd":
+        return _ssd_args(2, 4, 40, 16, 8), {"initial_state": rand((2, 4, 16, 8)),
+                                            "chunk": 16}
     x = rand((2, 40, 32))
     a = jnp.asarray(1 / (1 + np.exp(-RNG.normal(size=x.shape))), jnp.float32)
     return (x, a), {"initial_state": rand((2, 32)), "chunk": 16, "block_w": 16}
 
 
-@pytest.mark.parametrize("op", ["flash_attention", "wkv6", "rglru"])
+@pytest.mark.parametrize("op", ["flash_attention", "wkv6", "rglru", "ssd"])
 def test_ops_interpret_matches_ref(op):
     """The ops-level dispatch (argument plumbing, padding) around each kernel."""
     args, kw = _op_args(op)
@@ -341,7 +423,7 @@ from repro.models.layers import set_mesh_context
 import test_kernels as tk
 
 mesh = make_mesh((2, 2), ("data", "model"))
-for op in ("flash_attention", "wkv6", "rglru"):
+for op in ("flash_attention", "wkv6", "rglru", "ssd"):
     args, kw = tk._op_args(op)
     want = jax.tree.leaves(getattr(ops, op)(*args, impl="ref", **kw))
     set_mesh_context({"mesh": mesh, "dp_axes": ("data",), "model_axis": "model"})

@@ -111,8 +111,8 @@ def test_all_40_cells_enumerated():
     from repro.configs import ALL_CELLS
 
     cells = ALL_CELLS()
-    assert len(cells) == 40
-    assert len({a for a, _ in cells}) == 10
+    assert len(cells) == 44
+    assert len({a for a, _ in cells}) == 11
 
 
 def test_model_flops_scaling():

@@ -105,6 +105,20 @@ def test_rglru_compiles_at_recurrentgemma_9b_width(chip):
     _assert_kernel(compiled)
 
 
+def test_ssd_compiles_at_granite_width(chip):
+    """granite-4.0-h-micro's scan at the training cell's shape: 64 heads of
+    64, state 128, chunks of 256, bf16 operands."""
+    Bt, H, T, P, N = 2, 64, 2048, 64, 128
+    bf, f32 = jnp.bfloat16, jnp.float32
+
+    def scan(x, dt, A, B, C):
+        return ops.ssd(x, dt, A, B, C, chunk=256, impl="pallas")
+
+    compiled = _compile(scan, chip, ((Bt, H, T, P), bf), ((Bt, H, T), f32), ((H,), f32),
+                        ((Bt, T, N), bf), ((Bt, T, N), bf))
+    _assert_kernel(compiled)
+
+
 def _demo_step_compiled(params_sharding, batch_sharding):
     """Full-width serpytor-demo-100m step, global batch 8 x 1024, as chip_smoke.py trains it."""
     model = build(get_config("serpytor-demo-100m"))
