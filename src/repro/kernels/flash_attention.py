@@ -1,4 +1,4 @@
-"""Pallas TPU flash attention (fwd) — causal / local-window, GQA, MLA-ready.
+"""Pallas TPU flash attention — causal / local-window, GQA, MLA-ready.
 
 TPU-native design (not a CUDA port):
   - grid (B, Hq, Sq/bq, Sk/bk); the LAST grid dim is sequential on TPU
@@ -33,8 +33,29 @@ TPU-native design (not a CUDA port):
     ``repro_kernel_flash_blocks_total{kind="visited"|"total"}`` counters of
     ``repro.obs.metrics()`` (trace time, never per step).
 
-Backward: custom_vjp with a blocked pure-jnp recompute (flash-style, no S²
-materialization) in float32.
+Backward (custom_vjp, FlashAttention-2 style): the residuals are q, k, v and
+the forward's output; ``delta = rowsum(dO ∘ O)`` is float32 jnp. Three
+kernels on the backward's own tiles (``_pick_blocks`` with
+``_bwd_vmem_bytes`` and its larger budget, under a raised scoped-VMEM
+limit; the same override):
+  - ``_lse_kernel`` recomputes the row log-sum-exp, grid (B, Hq, nq, nk)
+    over sᵀ = k·qᵀ, so that the statistics lie along lanes;
+  - ``_dq_kernel``, grid (B, Hq, nq, nk): p = exp(s − lse),
+    ds = p ∘ (dO·vᵀ − delta), dq += ds·k in float32 scratch, written once;
+  - ``_dkv_kernel``, grid (B, Hkv, nk, g, nq), sequential over the group's
+    g query heads and the q blocks, on transposed tiles: dv += pᵀ·dO,
+    dk += dsᵀ·q in float32 scratch, written once per kv block.
+  Each skips and clamps as the forward does, by the forward's block plan
+  (``_block_plan``) or its transpose (``_block_plan_t``: per kv block, the q
+  blocks with any unmasked entry and with no masked one). A pair on the
+  mask's edge is computed in halves along each axis (``_halves``), and a
+  part holding no unmasked entry (``_any_kept``) is skipped: at 1024 x 1024
+  the causal diagonal costs three quarters of a tile, not a whole one. q enters scaled
+  in its dtype, as the forward scales it; q, k, v and dO enter the MXU in
+  the caller's dtype, p and ds are rounded to it for their products, and
+  every product, lse, delta and the accumulators are float32. Each lowering
+  adds its visited and total block pairs to
+  ``repro_kernel_flash_bwd_blocks_total{kind="visited"|"total"}``.
 """
 from __future__ import annotations
 
@@ -51,13 +72,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.obs.metrics import metrics
 
-from . import ref as _ref
-
 __all__ = ["flash_attention_pallas"]
 
 _NEG_INF = -1e30
 _MAX_BLOCK = 1024                # largest tile per axis
 _VMEM_BUDGET = 12 * 2**20        # bytes a call's tiles and scratch may take
+_BWD_VMEM_BUDGET = 24 * 2**20    # the same for a backward kernel, by its estimate
+_BWD_VMEM_LIMIT = 32 * 2**20     # scoped VMEM a backward kernel may take (v5e: 128 MiB)
 _LANES = 128
 
 
@@ -83,11 +104,13 @@ def _tile(n: int, cap: int) -> int:
     return min(range(_LANES, cap + 1, _LANES), key=lambda t: ((-n) % t, -t))
 
 
-def _pick_blocks(sq: int, sk: int, d: int, dv: int, itemsize: int):
-    """(bq, bk) for a call's shape: the largest tiles that fit the budget."""
+def _pick_blocks(sq: int, sk: int, d: int, dv: int, itemsize: int,
+                 vmem_bytes=_vmem_bytes, budget: int = _VMEM_BUDGET):
+    """(bq, bk) for a call's shape: the largest tiles whose ``vmem_bytes``
+    (the forward's by default) fit ``budget``."""
     for cap in range(_MAX_BLOCK, _LANES - 1, -_LANES):
         bq, bk = _tile(sq, cap), _tile(sk, cap)
-        if _vmem_bytes(bq, bk, d, dv, itemsize) <= _VMEM_BUDGET:
+        if vmem_bytes(bq, bk, d, dv, itemsize) <= budget:
             break
     return bq, bk
 
@@ -262,20 +285,339 @@ def flash_attention_pallas(q, k, v, causal=True, window=None, scale=None,
                      block_q=block_q, block_k=block_k, interpret=interpret)
 
 
+# --------------------------------------------------------------------------
+# backward
+# --------------------------------------------------------------------------
+
+_NT = (((1,), (1,)), ((), ()))   # a · bᵀ
+_NN = (((1,), (0,)), ((), ()))   # a · b
+
+
+def _bwd_vmem_bytes(bq: int, bk: int, d: int, dv: int, itemsize: int) -> int:
+    """VMEM of the larger backward kernel (dk/dv): double-buffered q, dO, k
+    and v tiles in and dk, dv tiles out, the lse and delta rows, the float32
+    (bk, bq) s/p, dp and ds tiles with p and ds in the operand dtype, and the
+    float32 dk, dv scratch, minor dims padded to lanes."""
+    lane = lambda n: -(-n // _LANES) * _LANES
+    tiles = 2 * itemsize * (bq + 2 * bk) * (lane(d) + lane(dv))
+    rows = 2 * 2 * 4 * 8 * lane(bq)
+    scores = (3 * 4 + 2 * itemsize) * bk * lane(bq)
+    scratch = 4 * bk * (lane(d) + lane(dv))
+    return tiles + rows + scores + scratch
+
+
+def _block_plan_t(nq, nk, bq, bk, sq, sk, causal, window) -> np.ndarray:
+    """The block plan by kv block (columns): the first and last q block
+    visiting it, the last kept in range for the DMA clamp, and the first and
+    last q block for which it is whole. Each set is a run of q blocks, since
+    the bounds of ``_kv_range`` and ``_whole_range`` rise with the q block."""
+    by_q = _block_plan(nq, bq, bk, sq, sk, causal, window)
+    plan = np.zeros((5, nk), np.int32)
+    for ik in range(nk):
+        runs = []
+        for lo, hi in ((0, 1), (3, 4)):
+            iqs = np.flatnonzero((by_q[lo] <= ik) & (ik <= by_q[hi]))
+            runs.append((iqs[0], iqs[-1]) if iqs.size else (0, -1))
+        (lo, hi), whole = runs
+        plan[:, ik] = (lo, hi, max(hi, lo), *whole)
+    return plan
+
+
+def _halves(n: int):
+    """Static (start, size) of the parts an edge tile of ``n`` splits into:
+    two halves when ``n`` is a multiple of 256 of at least 512, else one."""
+    return ((0, n // 2), (n // 2, n // 2)) if n >= 512 and n % 256 == 0 else ((0, n),)
+
+
+def _offsets(iq, ik, rows, cols, *, bq, bk, sq, sk, **_):
+    """Position of a sub-tile's first query and first key: rows ``rows``
+    (start, size) of q block ``iq``, columns ``cols`` of kv block ``ik``;
+    queries are right-aligned."""
+    return (lax.add(lax.mul(iq, bq), rows[0] + sk - sq),
+            lax.add(lax.mul(ik, bk), cols[0]))
+
+
+def _any_kept(iq, ik, rows, cols, *, sk, causal, window, **shape):
+    """Whether a sub-tile holds an unmasked entry: its keys kpos and query
+    positions qpos span intervals, so kpos − qpos spans one, which must meet
+    (−window, 0] and hold a real key."""
+    q0, k0 = _offsets(iq, ik, rows, cols, sk=sk, **shape)
+    k_last = lax.min(lax.add(k0, cols[1] - 1), sk - 1)
+    keep = [lax.le(k0, k_last)]
+    if causal:
+        keep.append(lax.le(k0, lax.add(q0, rows[1] - 1)))
+    if window is not None:
+        keep.append(lax.gt(k_last, lax.sub(q0, window)))
+    return functools.reduce(lax.bitwise_and, keep)
+
+
+def _keep(iq, ik, rows, cols, transposed: bool, *, sk, causal, window, **shape):
+    """Unmasked entries of a sub-tile, (rows, cols), or (cols, rows) when
+    ``transposed``."""
+    q0, k0 = _offsets(iq, ik, rows, cols, sk=sk, **shape)
+    t = int(transposed)
+    key = lax.broadcasted_iota(jnp.int32, (cols[1], 1) if t else (1, cols[1]), 1 - t)
+    query = lax.broadcasted_iota(jnp.int32, (1, rows[1]) if t else (rows[1], 1), t)
+    diff = key - query                                  # kpos − qpos + shift
+    shift = lax.sub(q0, k0)
+    keep = []
+    if causal:
+        keep.append(diff <= shift)
+    if window is not None:
+        keep.append(diff > shift - window)
+    if sk % shape["bk"]:
+        keep.append(key < lax.sub(sk, k0))
+    return functools.reduce(operator.and_, keep)
+
+
+def _visit(plan_ref, i, j, iq, ik, tile, **shape):
+    """Run ``tile(rows, cols, masked)`` for step ``j`` of row ``i`` of a
+    block plan: nothing outside its visited range, the whole tile unmasked
+    inside its whole range, and elsewhere each sub-tile (``_halves``) that
+    holds an unmasked entry, masked."""
+    bq, bk = shape["bq"], shape["bk"]
+    visit = _within(plan_ref[0, i], j, plan_ref[1, i])
+    full = functools.partial(tile, (0, bq), (0, bk), False)
+    if not (shape["causal"] or shape["window"] is not None or shape["sk"] % bk):
+        pl.when(visit)(full)
+        return
+    whole = _within(plan_ref[3, i], j, plan_ref[4, i])
+    pl.when(lax.bitwise_and(visit, whole))(full)
+
+    @pl.when(lax.bitwise_and(visit, lax.bitwise_not(whole)))
+    def _edge():
+        for rows in _halves(bq):
+            for cols in _halves(bk):
+                pl.when(_any_kept(iq, ik, rows, cols, **shape))(
+                    functools.partial(tile, rows, cols, True))
+
+
+def _rows(ref, part):
+    """Rows ``part`` (start, size) of a kernel's 2-D block ``ref[0, 0]``."""
+    return ref[0, 0, pl.ds(part[0], part[1]), :]
+
+
+def _lse_kernel(plan_ref, q_ref, k_ref, lse_ref, m_scr, l_scr, *, nk, **shape):
+    """Row log-sum-exp of the scaled scores, over sᵀ = k·qᵀ so that the
+    statistics lie along lanes, where the dk/dv kernel reads them."""
+    iq, ik = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(ik == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+
+    def tile(rows, cols, masked: bool):
+        s = lax.dot_general(_rows(k_ref, cols), _rows(q_ref, rows), _NT,
+                            preferred_element_type=jnp.float32)       # (cols, rows)
+        if masked:
+            s = jnp.where(_keep(iq, ik, rows, cols, True, **shape), s, _NEG_INF)
+        lanes = pl.ds(rows[0], rows[1])
+        m_prev = m_scr[:, lanes]                                      # (1, rows)
+        m_new = jnp.maximum(m_prev, s.max(axis=0, keepdims=True))
+        l_scr[:, lanes] = l_scr[:, lanes] * jnp.exp(m_prev - m_new) + jnp.exp(
+            s - m_new).sum(axis=0, keepdims=True)
+        m_scr[:, lanes] = m_new
+
+    _visit(plan_ref, iq, ik, iq, ik, tile, **shape)
+
+    @pl.when(ik == nk - 1)
+    def _write():
+        lse_ref[0, 0] = m_scr[...] + jnp.log(jnp.maximum(l_scr[...], 1e-37))
+
+
+def _dq_kernel(plan_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+               lse_scr, delta_scr, acc_scr, *, scale, nk, **shape):
+    """dq of one q block, accumulated over its kv blocks:
+    ds = p ∘ (dO·vᵀ − delta), dq = scale · ds·k."""
+    iq, ik = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(ik == 0)
+    def _init():
+        # the row statistics arrive along lanes; here a row is a sublane
+        lse_scr[...] = lse_ref[0, 0, 0][:, None]
+        delta_scr[...] = delta_ref[0, 0, 0][:, None]
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def tile(rows, cols, masked: bool):
+        k = _rows(k_ref, cols)
+        s = lax.dot_general(_rows(q_ref, rows), k, _NT, preferred_element_type=jnp.float32)
+        if masked:
+            s = jnp.where(_keep(iq, ik, rows, cols, False, **shape), s, _NEG_INF)
+        part = pl.ds(rows[0], rows[1])
+        p = jnp.exp(s - lse_scr[part, :])                             # (rows, cols)
+        dp = lax.dot_general(_rows(do_ref, rows), _rows(v_ref, cols), _NT,
+                             preferred_element_type=jnp.float32)
+        ds = p * (dp - delta_scr[part, :])
+        acc_scr[part, :] += lax.dot_general(ds.astype(k.dtype), k, _NN,
+                                            preferred_element_type=jnp.float32)
+
+    _visit(plan_ref, iq, ik, iq, ik, tile, **shape)
+
+    @pl.when(ik == nk - 1)
+    def _write():
+        dq_ref[0, 0] = (acc_scr[...] * scale).astype(dq_ref.dtype)
+
+
+def _dkv_kernel(plan_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
+                dk_scr, dv_scr, *, g, nq, **shape):
+    """dk and dv of one kv block, accumulated over the q blocks of each of
+    the group's g query heads, on transposed tiles: dv = pᵀ·dO, dk = dsᵀ·q
+    (q arrives scaled)."""
+    ik, ih, iq = pl.program_id(2), pl.program_id(3), pl.program_id(4)
+
+    @pl.when(lax.bitwise_and(ih == 0, iq == 0))
+    def _init():
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
+
+    def tile(rows, cols, masked: bool):
+        q, do = _rows(q_ref, rows), _rows(do_ref, rows)
+        s = lax.dot_general(_rows(k_ref, cols), q, _NT, preferred_element_type=jnp.float32)
+        if masked:
+            s = jnp.where(_keep(iq, ik, rows, cols, True, **shape), s, _NEG_INF)
+        lanes, part = pl.ds(rows[0], rows[1]), pl.ds(cols[0], cols[1])
+        p = jnp.exp(s - lse_ref[0, 0, :, lanes])                      # (cols, rows)
+        dv_scr[part, :] += lax.dot_general(p.astype(do.dtype), do, _NN,
+                                           preferred_element_type=jnp.float32)
+        dp = lax.dot_general(_rows(v_ref, cols), do, _NT, preferred_element_type=jnp.float32)
+        ds = p * (dp - delta_ref[0, 0, :, lanes])
+        dk_scr[part, :] += lax.dot_general(ds.astype(q.dtype), q, _NN,
+                                           preferred_element_type=jnp.float32)
+
+    _visit(plan_ref, ik, iq, iq, ik, tile, **shape)
+
+    @pl.when(lax.bitwise_and(ih == g - 1, iq == nq - 1))
+    def _write():
+        dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
+
+
+def _bwd_impl(q, k, v, out, dout, *, causal, window, scale, block_q, block_k, interpret):
+    """dq, dk, dv by three kernels on the backward's own tiles: the row
+    log-sum-exp, then dq by q block, then dk and dv by kv block. Each skips
+    the block pairs the mask leaves empty, as the forward does."""
+    B, Hq, Sq, D = q.shape
+    Hkv, Sk = k.shape[1], k.shape[2]
+    Dv = v.shape[-1]
+    g = Hq // Hkv
+    scale = scale if scale is not None else D ** -0.5
+    bq, bk = _pick_blocks(Sq, Sk, D, Dv, q.dtype.itemsize, _bwd_vmem_bytes, _BWD_VMEM_BUDGET)
+    bq = min(block_q, Sq) if block_q is not None else bq
+    bk = min(block_k, Sk) if block_k is not None else bk
+    nq, nk = -(-Sq // bq), -(-Sk // bk)
+    pad = lambda x, n: jnp.pad(x, ((0, 0), (0, 0), (0, n), (0, 0))) if n else x
+    # q scaled once in its dtype, as the forward scales it; padded rows of
+    # dO (and so of delta) are zero, so they add nothing to dk and dv
+    qs = pad((q.astype(jnp.float32) * scale).astype(q.dtype), nq * bq - Sq)
+    do = pad(dout.astype(q.dtype), nq * bq - Sq)
+    kp, vp = pad(k, nk * bk - Sk), pad(v, nk * bk - Sk)
+    delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    delta = jnp.pad(delta, ((0, 0), (0, 0), (0, nq * bq - Sq)))[:, :, None, :]
+    shape = dict(bq=bq, bk=bk, sq=Sq, sk=Sk, causal=causal, window=window)
+    plan = _block_plan(nq, **shape)
+
+    reg = metrics()
+    visited = int(np.maximum(plan[1] - plan[0] + 1, 0).sum())
+    reg.counter("repro_kernel_flash_bwd_blocks_total", kind="visited").inc(B * Hq * visited)
+    reg.counter("repro_kernel_flash_bwd_blocks_total", kind="total").inc(B * Hq * nq * nk)
+
+    f32 = jnp.float32
+    row = (1, 1, 1, bq)
+
+    # by q block: the stats and dq kernels, grid (B, Hq, nq, nk)
+    def q_index(b, h, iq, ik, plan):
+        return b, h, iq, 0
+
+    def row_index(b, h, iq, ik, plan):
+        return b, h, 0, iq
+
+    def kv_index(b, h, iq, ik, plan):
+        # a skipped step repeats a block the pipeline holds: no new copy
+        return b, lax.div(h, g), lax.clamp(plan[0, iq], ik, plan[2, iq]), 0
+
+    by_q = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=_BWD_VMEM_LIMIT)
+    lse = pl.pallas_call(
+        functools.partial(_lse_kernel, nk=nk, **shape),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, Hq, nq, nk),
+            in_specs=[pl.BlockSpec((1, 1, bq, D), q_index),
+                      pl.BlockSpec((1, 1, bk, D), kv_index)],
+            out_specs=pl.BlockSpec(row, row_index),
+            scratch_shapes=[pltpu.VMEM((1, bq), f32), pltpu.VMEM((1, bq), f32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, 1, nq * bq), f32),
+        compiler_params=by_q,
+        interpret=interpret,
+    )(jnp.asarray(plan), qs, kp)
+
+    dq = pl.pallas_call(
+        functools.partial(_dq_kernel, scale=scale, nk=nk, **shape),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, Hq, nq, nk),
+            in_specs=[pl.BlockSpec((1, 1, bq, D), q_index),
+                      pl.BlockSpec((1, 1, bk, D), kv_index),
+                      pl.BlockSpec((1, 1, bk, Dv), kv_index),
+                      pl.BlockSpec((1, 1, bq, Dv), q_index),
+                      pl.BlockSpec(row, row_index),
+                      pl.BlockSpec(row, row_index)],
+            out_specs=pl.BlockSpec((1, 1, bq, D), q_index),
+            scratch_shapes=[pltpu.VMEM((bq, 1), f32), pltpu.VMEM((bq, 1), f32),
+                            pltpu.VMEM((bq, D), f32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, nq * bq, D), q.dtype),
+        compiler_params=by_q,
+        interpret=interpret,
+    )(jnp.asarray(plan), qs, kp, vp, do, lse, delta)
+
+    # by kv block: the dk/dv kernel, grid (B, Hkv, nk, g, nq)
+    def qt_index(b, hk, ik, ih, iq, plan):
+        return b, hk * g + ih, lax.clamp(plan[0, ik], iq, plan[2, ik]), 0
+
+    def rowt_index(b, hk, ik, ih, iq, plan):
+        return b, hk * g + ih, 0, lax.clamp(plan[0, ik], iq, plan[2, ik])
+
+    def kvt_index(b, hk, ik, ih, iq, plan):
+        return b, hk, ik, 0
+
+    dk, dv = pl.pallas_call(
+        functools.partial(_dkv_kernel, g=g, nq=nq, **shape),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, Hkv, nk, g, nq),
+            in_specs=[pl.BlockSpec((1, 1, bq, D), qt_index),
+                      pl.BlockSpec((1, 1, bk, D), kvt_index),
+                      pl.BlockSpec((1, 1, bk, Dv), kvt_index),
+                      pl.BlockSpec((1, 1, bq, Dv), qt_index),
+                      pl.BlockSpec(row, rowt_index),
+                      pl.BlockSpec(row, rowt_index)],
+            out_specs=[pl.BlockSpec((1, 1, bk, D), kvt_index),
+                       pl.BlockSpec((1, 1, bk, Dv), kvt_index)],
+            scratch_shapes=[pltpu.VMEM((bk, D), f32), pltpu.VMEM((bk, Dv), f32)],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((B, Hkv, nk * bk, D), k.dtype),
+                   jax.ShapeDtypeStruct((B, Hkv, nk * bk, Dv), v.dtype)],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_BWD_VMEM_LIMIT),
+        interpret=interpret,
+    )(jnp.asarray(_block_plan_t(nq, nk, **shape)), qs, kp, vp, do, lse, delta)
+    return dq[:, :, :Sq], dk[:, :, :Sk], dv[:, :, :Sk]
+
+
 def _vjp_fwd(q, k, v, causal, window, scale, block_q, block_k, interpret):
     out = _fwd_impl(q, k, v, causal=causal, window=window, scale=scale,
                     block_q=block_q, block_k=block_k, interpret=interpret)
-    return out, (q, k, v)
+    return out, (q, k, v, out)
 
 
 def _vjp_bwd(causal, window, scale, block_q, block_k, interpret, res, dout):
-    q, k, v = res
-    # blocked recompute bwd (pure jnp, flash-style memory profile)
-    f = lambda q_, k_, v_: _ref.flash_attention_ref(
-        q_, k_, v_, causal=causal, window=window, scale=scale,
-        block_k=max(block_k or 128, 128))
-    _, vjp = jax.vjp(f, q, k, v)
-    return vjp(dout)
+    return _bwd_impl(*res, dout, causal=causal, window=window, scale=scale,
+                     block_q=block_q, block_k=block_k, interpret=interpret)
 
 
 flash_attention_pallas.defvjp(_vjp_fwd, _vjp_bwd)

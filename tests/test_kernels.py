@@ -133,6 +133,108 @@ def test_flash_block_plan_is_exact(causal, window):
     assert after[1] - before[1] == B * H * nq * nk
 
 
+def _flash_bwd_blocks():
+    reg = metrics()
+    return (reg.counter("repro_kernel_flash_bwd_blocks_total", kind="visited").value,
+            reg.counter("repro_kernel_flash_bwd_blocks_total", kind="total").value)
+
+
+@pytest.mark.parametrize("causal, window", [(True, None), (True, 1), (True, 20), (True, 100),
+                                            (False, None), (False, 30)])
+def test_flash_block_plan_by_kv_block_is_exact(causal, window):
+    """The dk/dv kernel's plan: q block iq visits kv block ik iff some entry
+    of its real rows is unmasked, the pair takes the unmasked path iff none
+    is masked or padded, the clamped q index is itself when visited and in
+    range always, and the backward's counter adds up."""
+    for sq, sk in [(1, 1), (17, 17), (40, 96), (96, 96), (70, 130), (130, 130)]:
+        keep = _dense_mask(sq, sk, causal, window)
+        for bq, bk in [(16, 16), (16, 48), (48, 16), (32, 32)]:
+            nq, nk = -(-sq // bq), -(-sk // bk)
+            plan = fa._block_plan_t(nq, nk, bq, bk, sq, sk, causal, window)
+            for ik in range(nk):
+                lo, hi, hi_dma, whole_lo, whole_hi = plan[:, ik]
+                for iq in range(nq):
+                    blk = keep[iq * bq:(iq + 1) * bq, ik * bk:(ik + 1) * bk]
+                    visit = lo <= iq <= hi
+                    assert visit == blk.any(), (sq, sk, bq, bk, iq, ik)
+                    whole = blk.all() and (ik + 1) * bk <= sk
+                    assert (whole_lo <= iq <= whole_hi) == whole, (sq, sk, bq, bk, iq, ik)
+                    clamped = min(max(iq, lo), hi_dma)
+                    assert 0 <= clamped < nq and (clamped == iq or not visit)
+
+    B, H, sq, sk, bq, bk = 2, 3, 70, 130, 32, 48
+    x = jax.ShapeDtypeStruct((B, H, sq, 16), jnp.float32)
+    kv = jax.ShapeDtypeStruct((B, H, sk, 16), jnp.float32)
+    f = lambda q, k, v: flash_attention_pallas(q, k, v, causal, window, None, bq, bk, True)
+    before = _flash_bwd_blocks()
+    jax.eval_shape(lambda q, k, v: jax.vjp(f, q, k, v)[1](q), x, kv, kv)
+    after = _flash_bwd_blocks()
+    want = _dense_mask(sq, sk, causal, window)
+    nq, nk = -(-sq // bq), -(-sk // bk)
+    blocks = sum(want[i * bq:(i + 1) * bq, j * bk:(j + 1) * bk].any()
+                 for i in range(nq) for j in range(nk))
+    assert after[0] - before[0] == B * H * blocks
+    assert after[1] - before[1] == B * H * nq * nk
+    if causal:
+        assert blocks < nq * nk
+
+
+def test_flash_backward_skips_masked_blocks_of_the_training_call():
+    """At the training call's shape the backward's tiles are 1024 x 1024 and
+    the causal skip leaves 3 of the 4 block pairs of each head."""
+    x = jax.ShapeDtypeStruct((2, 32, 2048, 64), jnp.bfloat16)
+    f = lambda q, k, v: flash_attention_pallas(q, k, v, True)
+    before = _flash_bwd_blocks()
+    jax.eval_shape(lambda q, k, v: jax.vjp(f, q, k, v)[1](q), x, x, x)
+    after = _flash_bwd_blocks()
+    assert (after[0] - before[0], after[1] - before[1]) == (64 * 3, 64 * 4)
+
+
+@pytest.mark.parametrize("causal, window", [(True, None), (True, 1), (True, 100),
+                                            (False, None), (False, 30)])
+def test_flash_backward_sub_tiles_skip_only_masked_entries(causal, window):
+    """An edge tile's part is skipped only when every entry of its real rows
+    is masked or a padded key; one without padded rows is visited iff some
+    entry is unmasked."""
+    assert fa._halves(1024) == ((0, 512), (512, 512))
+    assert fa._halves(768) == ((0, 384), (384, 384))
+    assert fa._halves(640) == ((0, 640),) and fa._halves(256) == ((0, 256),)
+    for sq, sk, bq, bk in [(96, 96, 32, 32), (70, 130, 32, 48), (40, 97, 32, 48),
+                           (40, 200, 48, 64)]:
+        keep = _dense_mask(sq, sk, causal, window)
+        shape = dict(bq=bq, bk=bk, sq=sq, sk=sk, causal=causal, window=window)
+        for iq in range(-(-sq // bq)):
+            for ik in range(-(-sk // bk)):
+                for rows in [(0, bq), (0, bq // 2), (bq // 2, bq // 2)]:
+                    for cols in [(0, bk), (0, bk // 2), (bk // 2, bk // 2)]:
+                        r0, c0 = iq * bq + rows[0], ik * bk + cols[0]
+                        real = keep[r0:min(r0 + rows[1], sq), c0:c0 + cols[1]]
+                        kept = bool(fa._any_kept(iq, ik, rows, cols, **shape))
+                        if r0 + rows[1] <= sq:
+                            assert kept == real.any(), (sq, sk, iq, ik, rows, cols)
+                        else:
+                            assert kept or not real.any(), (sq, sk, iq, ik, rows, cols)
+
+
+@pytest.mark.parametrize("sq, sk, d, dv, itemsize, want", [
+    (2048, 2048, 64, 64, 2, (1024, 1024)),      # stablelm-1.6b training call
+    (2048, 2048, 64, 64, 4, (512, 512)),        # float32 tiles: budget binds
+    (4096, 4096, 256, 256, 2, (512, 512)),      # RecurrentGemma's window layers
+    (2048, 2048, 192, 128, 2, (1024, 1024)),    # MLA widths
+    (17, 17, 128, 128, 2, (17, 17)),            # short, unaligned: whole length
+    (300, 1000, 64, 64, 4, (300, 1000)),
+    (8192, 8192, 1024, 1024, 4, None),          # the budget's floor
+])
+def test_pick_backward_blocks_from_shape(sq, sk, d, dv, itemsize, want):
+    bq, bk = fa._pick_blocks(sq, sk, d, dv, itemsize, fa._bwd_vmem_bytes, fa._BWD_VMEM_BUDGET)
+    if want is not None:
+        assert (bq, bk) == want
+    for n, t in ((sq, bq), (sk, bk)):
+        assert t == n or (t % 128 == 0 and 0 <= (-n) % t < t)
+    if (bq, bk) != (min(sq, 128), min(sk, 128)):
+        assert fa._bwd_vmem_bytes(bq, bk, d, dv, itemsize) <= fa._BWD_VMEM_BUDGET
+
+
 @pytest.mark.parametrize("sq, sk, d, dv, itemsize, want", [
     (2048, 2048, 64, 64, 2, (1024, 1024)),      # stablelm-1.6b training call
     (4096, 4096, 128, 128, 2, (1024, 1024)),    # qwen3-1.7b longest prefill
@@ -175,6 +277,61 @@ def test_flash_kernel_grad_matches_oracle_grad():
                                    rtol=1e-3, atol=1e-3)
 
 
+def _grad_gaps(q, k, v, causal, window, bq, bk):
+    """Largest gap of each of dq, dk, dv of the kernel from the dense
+    oracle's gradient (in float32, on the same inputs), over the largest
+    entry of the oracle's gradient. The loss weighs the output at random."""
+    w = rand(q.shape[:3] + v.shape[3:])
+    f32 = lambda x: x.astype(jnp.float32)
+
+    def f_kernel(q, k, v):
+        return (f32(flash_attention_pallas(q, k, v, causal, window, None, bq, bk, True))
+                * w).sum()
+
+    def f_ref(q, k, v):
+        return (ref.flash_attention_dense_ref(f32(q), f32(k), f32(v), causal=causal,
+                                              window=window) * w).sum()
+
+    got = jax.grad(f_kernel, argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
+    for a, x in zip(got, (q, k, v), strict=True):
+        assert a.shape == x.shape and a.dtype == x.dtype
+    return [float(jnp.max(jnp.abs(f32(a) - b)) / jnp.max(jnp.abs(b)))
+            for a, b in zip(got, want, strict=True)]
+
+
+FLASH_GRAD_CASES = [
+    # (B, Hq, Hkv, Sq, Sk, D, Dv, causal, window, block_q, block_k)
+    (1, 2, 2, 128, 128, 32, 32, True, None, 32, 32),     # causal, group 1
+    (1, 2, 2, 128, 128, 32, 32, False, None, 32, 64),    # bidirectional
+    (1, 2, 2, 128, 128, 32, 32, True, 40, 32, 32),       # local window
+    (1, 8, 2, 128, 128, 32, 32, True, 40, 32, 32),       # GQA group 4, window
+    (1, 4, 1, 128, 128, 32, 32, True, None, 64, 32),     # MQA, bq > bk
+    (1, 2, 2, 128, 128, 48, 32, True, None, 32, 64),     # MLA: D != Dv, bq < bk
+    (1, 2, 2, 96, 256, 32, 32, True, None, 32, 64),      # Sq < Sk, right-aligned
+    (1, 2, 1, 90, 170, 32, 32, True, 70, 32, 64),        # window, q and k padded
+    (1, 2, 2, 64, 200, 32, 32, False, None, 32, 64),     # bidirectional, keys padded
+    (2, 4, 2, 40, 40, 16, 16, True, None, None, None),   # tiles from the shape
+    (1, 2, 1, 1000, 1000, 32, 32, True, 300, 512, 512),  # edge tiles in halves, padded
+    (1, 2, 2, 600, 1100, 32, 32, False, 200, 512, 768),  # halves of 256 and 384
+]
+#: float32: the kernel's own rounding; bfloat16: operands, p and ds rounded
+#: to bf16 on the MXU against the float32 oracle
+GRAD_TOL = {"f32": 1e-5, "bf16": 2e-2}
+
+
+@pytest.mark.parametrize("dtype", ["f32", "bf16"])
+@pytest.mark.parametrize("case", FLASH_GRAD_CASES)
+def test_flash_kernel_grads_match_dense_oracle(case, dtype):
+    B, Hq, Hkv, Sq, Sk, D, Dv, causal, window, bq, bk = case
+    dt = {"f32": jnp.float32, "bf16": jnp.bfloat16}[dtype]
+    q = rand((B, Hq, Sq, D), dt)
+    k = rand((B, Hkv, Sk, D), dt)
+    v = rand((B, Hkv, Sk, Dv), dt)
+    gaps = _grad_gaps(q, k, v, causal, window, bq, bk)
+    assert max(gaps) <= GRAD_TOL[dtype], gaps
+
+
 @settings(max_examples=8, deadline=None)
 @given(st.integers(1, 3), st.integers(0, 2), st.integers(1, 4),
        st.booleans(), st.sampled_from([None, 32]))
@@ -189,6 +346,7 @@ def test_flash_kernel_property_sweep(b, hkv_pow, sq_blocks, causal, window):
     want = ref.flash_attention_dense_ref(q, k, v, causal=causal, window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=3e-5, atol=3e-5)
+    assert max(_grad_gaps(q, k, v, causal, window, 64, 64)) <= GRAD_TOL["f32"]
 
 
 # ---------------------------------------------------------------------------
@@ -434,6 +592,18 @@ for op in ("flash_attention", "wkv6", "rglru", "ssd"):
     assert "manual_computation" in text, op
     for g, w in zip(got, want, strict=True):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-4, atol=2e-4)
+
+# the flash backward's kernels run inside the same per-shard body as the forward
+args, kw = tk._op_args("flash_attention")
+loss = lambda impl: lambda *a: (ops.flash_attention(*a, impl=impl, **kw) ** 2).sum()
+want = jax.grad(loss("ref"), argnums=(0, 1, 2))(*args)
+set_mesh_context({"mesh": mesh, "dp_axes": ("data",), "model_axis": "model"})
+f = jax.jit(jax.grad(loss("interpret"), argnums=(0, 1, 2)))
+assert f.lower(*args).as_text().count("manual_computation") >= 2
+got = f(*args)
+set_mesh_context(None)
+for g, w in zip(got, want, strict=True):
+    np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-4, atol=2e-4)
 print("per-shard ok")
 """
 

@@ -8,7 +8,10 @@ fixture, never at import, so that every pytest-xdist worker collects the same
 tests and only the worker running this file loads the TPU library.
 """
 
+import importlib.util
 import os
+from pathlib import Path
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -63,6 +66,32 @@ def _assert_kernel(compiled):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _bench_kernel(name):
+    """``bench/kernels/<name>.py``, loaded by path as the benchmark loads it."""
+    path = Path(__file__).resolve().parents[1] / "bench" / "kernels" / f"{name}.py"
+    spec = importlib.util.spec_from_file_location(f"bench_kernels_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _mosaic_calls(compiled):
+    """The program's Mosaic calls as the profiler names them: HLO text with
+    each operand's shape."""
+    from jax._src.lib import xla_client
+
+    opts = xla_client._xla.HloPrintOptions.short_parsable()
+    opts.print_operand_shape = True
+    text = compiled.runtime_executable().hlo_modules()[0].to_string(opts)
+    return [SimpleNamespace(name=line.strip()) for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+def _flash_call(q, kv):
+    return {"batch": q[0], "heads": q[1], "kv_heads": kv[1], "q_len": q[2], "kv_len": kv[2],
+            "head_dim": q[3], "causal": True, "dtype_bytes": 2}
+
+
 @pytest.mark.parametrize(
     "name, q, kv, dtype",
     [
@@ -77,6 +106,36 @@ def test_flash_attention_compiles(chip, name, q, kv, dtype):
         return ops.flash_attention(q, k, v, causal=True, impl="pallas")
 
     _assert_kernel(_compile(fwd, chip, (q, dtype), (kv, dtype), (kv, dtype)))
+
+
+@pytest.mark.parametrize(
+    "name, q, kv",
+    [
+        ("stablelm-1.6b training call", (2, 32, 2048, 64), (2, 32, 2048, 64)),
+        ("granite-4.0-h-micro attention layer", (2, 32, 2048, 64), (2, 8, 2048, 64)),
+    ],
+)
+def test_flash_attention_backward_compiles(chip, name, q, kv):
+    """``jax.vjp`` of one call: the forward and the backward's three kernels
+    (log-sum-exp, dq, dk/dv) within 256 MiB of temporaries, where the
+    float32 jnp backward took 2.91 GiB; the benchmark's forward matcher takes
+    the forward alone, and its backward matcher the three others."""
+    bf = jnp.bfloat16
+
+    def vjp(q, k, v, do):
+        _, pull = jax.vjp(lambda *a: ops.flash_attention(*a, causal=True, impl="pallas"), q, k, v)
+        return pull(do)
+
+    compiled = _compile(vjp, chip, (q, bf), (kv, bf), (kv, bf), (q, bf))
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2**20
+    calls = _mosaic_calls(compiled)
+    call = _flash_call(q, kv)
+    fwd = [c for c in calls if _bench_kernel("flash_attention").matcher(call)(c)]
+    bwd_kernels = _bench_kernel("flash_attention_bwd")
+    bwd = [c for c in calls if bwd_kernels.matcher(call)(c)]
+    dkv = [c for c in calls if bwd_kernels.dkv_matcher(call)(c)]
+    assert (len(calls), len(fwd), len(bwd), len(dkv)) == (4, 1, 3, 1)
+    assert fwd[0] not in bwd and dkv[0] in bwd
 
 
 def test_wkv6_compiles_at_rwkv6_7b_width(chip):
@@ -169,3 +228,13 @@ def test_demo_100m_train_step_compiles_data_parallel_on_four_chips(topo):
     assert "tpu_custom_call" in text and "all-reduce" in text
     total = _device_bytes(compiled)
     assert total < HBM_BYTES, f"train step needs {total / 2**30:.2f} GiB per chip"
+    # the backward's kernels run per shard too, on 2 of the 8 rows (once,
+    # in the body of the loop over the stacked layers)
+    cfg = get_config("serpytor-demo-100m")
+    shard = _flash_call((2, cfg.num_heads, 1024, cfg.head_dim),
+                        (2, cfg.num_kv_heads, 1024, cfg.head_dim))
+    calls = _mosaic_calls(compiled)
+    bwd_kernels = _bench_kernel("flash_attention_bwd")
+    dkv = sum(map(bwd_kernels.dkv_matcher(shard), calls))
+    assert dkv >= 1
+    assert sum(map(bwd_kernels.matcher(shard), calls)) == 3 * dkv
