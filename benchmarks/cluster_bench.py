@@ -20,15 +20,8 @@ Under the barrier, every level's wall-clock is the slow worker's wall-clock;
 under dataflow only the diamonds whose tasks actually landed on the slow
 worker are delayed (and speculation covers even those).
 
-A second leg exercises the asyncio control plane's inflight ceiling: with
-``--inflight N`` the bench submits N trivial tasks through an
-:class:`~repro.core.AsyncGateway` at once and reports sustained completion
-throughput — the threaded runtime's thread-per-dispatch pump tops out at a
-few hundred inflight; the event-loop runtime is expected to take 10k+.
-
 Run:   PYTHONPATH=src python -m benchmarks.cluster_bench
        PYTHONPATH=src python -m benchmarks.cluster_bench --smoke --json out.json
-       PYTHONPATH=src python -m benchmarks.cluster_bench --inflight 10000
 
 Prints CSV-ish lines like benchmarks/run.py; ``--json`` additionally writes a
 machine-readable result blob (consumed by the CI bench-smoke artifact step).
@@ -43,7 +36,6 @@ import time
 
 from repro.core import (
     EMPTY_CONTEXT,
-    AsyncGateway,
     ClusterExecutor,
     ContextGraph,
     Gateway,
@@ -147,47 +139,6 @@ def bench(args: argparse.Namespace) -> dict:
     return result
 
 
-def bench_inflight(args: argparse.Namespace) -> dict:
-    """Async-runtime inflight ceiling: N concurrent trivial tasks, one host.
-
-    Every task is submitted before the first result is collected, so the
-    gateway genuinely holds ``--inflight`` outstanding requests; the leg
-    fails loudly if any future is lost, times out, or returns the wrong
-    value — completion correctness at scale is the point, not just speed.
-    """
-    n = args.inflight
-    reg = TaskRegistry()
-
-    @reg.task("noop")
-    def noop(ctx, i=0):
-        return i + 1
-
-    workers = [
-        InProcWorker(f"w{i}", reg, max_concurrency=256) for i in range(args.workers)
-    ]
-    with AsyncGateway(workers, max_inflight_rpc=1024) as gw:
-        t0 = time.perf_counter()
-        futs = gw.map("noop", [{"i": i} for i in range(n)])
-        submit_s = time.perf_counter() - t0
-        results = [f.result(timeout=300) for f in futs]
-        wall_s = time.perf_counter() - t0
-    assert results == [i + 1 for i in range(n)], "lost or corrupted completions"
-    throughput = n / wall_s if wall_s else float("inf")
-    result = {
-        "inflight": n,
-        "workers": args.workers,
-        "runtime": "async",
-        "submit_wall_s": round(submit_s, 4),
-        "wall_s": round(wall_s, 4),
-        "tasks_per_s": round(throughput, 1),
-        "outputs_ok": True,
-    }
-    print(f"inflight,{n}")
-    print(f"inflight_wall_s,{wall_s * 1e3:.1f}ms")
-    print(f"inflight_tasks_per_s,{throughput:.0f}")
-    return result
-
-
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--diamonds", type=int, default=12)
@@ -208,23 +159,9 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true", help="tiny sizes, assert-no-crash")
     ap.add_argument("--json", type=str, default="", help="write the result blob to this path")
     ap.add_argument("--out", type=str, default=".", help="directory for the run journal")
-    ap.add_argument(
-        "--inflight",
-        type=int,
-        default=0,
-        help="run ONLY the async-runtime inflight leg with N concurrent tasks",
-    )
     args = ap.parse_args()
 
     os.makedirs(args.out, exist_ok=True)
-    if args.inflight:
-        result = bench_inflight(args)
-        if args.json:
-            with open(args.json, "w") as fh:
-                json.dump(result, fh, indent=2)
-            print(f"# wrote {args.json}")
-        return
-
     runs = [bench(args) for _ in range(1 if args.smoke else args.repeat)]
     best = dict(runs[0])
     # best-of-N per MODE (not per run): each mode's floor is its honest cost

@@ -11,8 +11,7 @@ Two layers (docs/static-analysis.md):
     ``REPRO_LINT`` env var), so the contract travels with user code.
   - **Repo-invariant checks** (``INVxxx``) — lint the framework tree
     itself: journal-kind exhaustiveness across the four switch sites,
-    the wall-vs-monotonic clock policy, and blocking calls in the asyncio
-    control plane. Run via ``python -m repro lint``.
+    and the wall-vs-monotonic clock policy. Run via ``python -m repro lint``.
 
 Pure stdlib (``ast``, ``inspect``, ``dis``); importing this package pulls
 in none of the runtime.
@@ -29,7 +28,6 @@ from .findings import (
 )
 from .invariants import (
     KIND_SITES,
-    check_async_blocking,
     check_clock_policy,
     check_kind_exhaustiveness,
     known_kinds,
@@ -42,7 +40,6 @@ __all__ = [
     "KIND_SITES",
     "ReplayUnsafeError",
     "ReplayUnsafeWarning",
-    "check_async_blocking",
     "check_callable",
     "check_clock_policy",
     "check_graph",

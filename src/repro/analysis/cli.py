@@ -6,7 +6,6 @@ Walks the given paths (default: ``src``) for Python files and runs:
     function found statically (``@atomic_task``, ``@graph.task(...)``, and
     callables passed to ``Graph.add``/``add_stream``);
   - the clock-policy check (INV201) over files inside ``src/repro``;
-  - the async-blocking checks (INV301/INV302) over ``src/repro/core/aio``;
   - the journal-kind exhaustiveness check (INV101/INV102) once per
     invocation, against the repo's four switch sites.
 
@@ -26,11 +25,7 @@ import sys
 from typing import Iterable, List, Optional, Sequence, Tuple
 
 from .findings import CODES, Finding, load_baseline, split_baselined, write_baseline
-from .invariants import (
-    check_async_blocking,
-    check_clock_policy,
-    check_kind_exhaustiveness,
-)
+from .invariants import check_clock_policy, check_kind_exhaustiveness
 from .replay import check_source_tasks
 
 __all__ = ["add_lint_parser", "cmd_lint", "find_repo_root", "lint_paths", "main"]
@@ -122,8 +117,6 @@ def lint_paths(
         findings.extend(check_source_tasks(text, path=rel, package=package))
         if rel.startswith("src/repro/"):
             findings.extend(check_clock_policy(text, path=rel, package=package))
-        if rel.startswith("src/repro/core/aio/"):
-            findings.extend(check_async_blocking(text, path=rel, package=package))
     if kind_checks and os.path.isdir(os.path.join(root, "src", "repro")):
         # repo-level pass: only meaningful when the framework tree itself
         # is under this root (out-of-tree user code has no switch sites)

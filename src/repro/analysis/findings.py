@@ -60,8 +60,6 @@ CODES: Dict[str, Tuple[str, str]] = {
     "INV101": ("journal-kinds", "journal kind not handled or declared-ignored at a switch site"),
     "INV102": ("journal-kinds", "stale kind at a switch site (absent from KNOWN_KINDS)"),
     "INV201": ("clock-policy", "time.time() call site without a policy justification comment"),
-    "INV301": ("async-blocking", "blocking call inside an async def in the asyncio control plane"),
-    "INV302": ("async-blocking", "threaded control-plane entry point constructed in a coroutine"),
     "E999": ("parse", "file could not be parsed (syntax error or unreadable)"),
 }
 

@@ -1,6 +1,6 @@
 """Repo-invariant checks: lint the framework against its own conventions.
 
-Three invariant families, each enforcing a contract the code cannot express
+Two invariant families, each enforcing a contract the code cannot express
 in types (docs/static-analysis.md §3):
 
   - ``INV101``/``INV102`` — **journal-kind exhaustiveness.** Four
@@ -21,24 +21,19 @@ in types (docs/static-analysis.md §3):
     Every remaining ``time.time()`` must carry a justification comment —
     ``# record timestamp`` or ``# wall-clock: <reason>`` — on its own or
     the preceding line.
-  - ``INV301``/``INV302`` — **async blocking calls.** Beyond ruff's ASYNC
-    family: inside ``core/aio`` coroutine bodies, flag ``time.sleep``,
-    synchronous file/process/network calls, and construction of the
-    *threaded* control-plane entry points (``Gateway``, ``WorkerServer``).
 """
 
 from __future__ import annotations
 
 import ast
 import re
-from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
+from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 from .findings import Finding
 from .replay import StaticResolver, _canonical
 
 __all__ = [
     "KIND_SITES",
-    "check_async_blocking",
     "check_clock_policy",
     "check_kind_exhaustiveness",
     "collect_kind_coverage",
@@ -60,31 +55,6 @@ KIND_SITES: Tuple[Tuple[str, str, str, str], ...] = (
 CLOCK_JUSTIFICATION = re.compile(r"#\s*(record timestamp|wall[- ]clock)", re.IGNORECASE)
 
 _WALL_CALLS = frozenset({"time.time", "time.time_ns"})
-
-#: Blocking calls that must not appear inside a coroutine body (INV301).
-_ASYNC_BLOCKING = frozenset(
-    {
-        "time.sleep",
-        "open",
-        "io.open",
-        "os.system",
-        "os.popen",
-        "socket.create_connection",
-        "socket.getaddrinfo",
-    }
-)
-_ASYNC_BLOCKING_PREFIXES = ("subprocess.", "requests.", "urllib.request.")
-
-#: Threaded control-plane entry points whose construction inside a
-#: coroutine would run a thread-per-dispatch engine on the event loop
-#: (INV302). The asyncio twins are the legal spellings there.
-_THREADED_ENTRY_POINTS = frozenset(
-    {
-        "repro.core.gateway.Gateway",
-        "repro.core.server.WorkerServer",
-    }
-)
-
 
 def known_kinds() -> Set[str]:
     """The journal-kind vocabulary, read from the runtime source of truth.
@@ -284,63 +254,3 @@ def check_clock_policy(
         )
     return findings
 
-
-# -- async blocking ---------------------------------------------------------
-
-
-def _async_bodies(tree: ast.Module) -> Iterable[Tuple[str, ast.AsyncFunctionDef]]:
-    for node in ast.walk(tree):
-        if isinstance(node, ast.AsyncFunctionDef):
-            yield node.name, node
-
-
-def check_async_blocking(
-    text: str, path: str = "", package: Sequence[str] = ()
-) -> List[Finding]:
-    """INV301/INV302 findings for one ``core/aio`` file."""
-    try:
-        tree = ast.parse(text)
-    except SyntaxError:
-        return []
-    resolver = StaticResolver(tree, package=package)
-    lines = text.splitlines()
-    findings: List[Finding] = []
-
-    def emit(code: str, message: str, node: ast.AST, symbol: str) -> None:
-        lineno = getattr(node, "lineno", 0)
-        findings.append(
-            Finding(
-                code=code,
-                message=message,
-                path=path,
-                line=lineno,
-                symbol=symbol,
-                snippet=lines[lineno - 1].strip() if 0 < lineno <= len(lines) else "",
-            )
-        )
-
-    for name, fn_node in _async_bodies(tree):
-        for node in ast.walk(fn_node):
-            if not isinstance(node, ast.Call):
-                continue
-            canon = _canonical(resolver, node.func, set())
-            if canon is None:
-                continue
-            if canon in _ASYNC_BLOCKING or canon.startswith(_ASYNC_BLOCKING_PREFIXES):
-                emit(
-                    "INV301",
-                    f"blocking call {canon}() inside coroutine {name!r} — "
-                    "stalls the event loop; use the asyncio equivalent or "
-                    "offload to a thread",
-                    node,
-                    name,
-                )
-            elif canon in _THREADED_ENTRY_POINTS:
-                emit(
-                    "INV302",
-                    f"threaded entry point {canon}(...) constructed inside "
-                    f"coroutine {name!r} — use the asyncio twin",
-                    node,
-                    name,
-                )
-    return findings

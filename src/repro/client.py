@@ -28,8 +28,7 @@ Re-running the same ``run_id`` resumes from its journal (replay, then
 continue) — that is the durability contract, not an error. ``cluster``
 accepts a list of workers (the client builds and owns a :class:`Gateway`,
 or a :class:`ShardedGateway` when ``shards > 1``) or a prebuilt
-gateway-like object (caller keeps ownership). ``REPRO_RUNTIME=async``
-transparently selects the asyncio control plane underneath either form.
+gateway-like object (caller keeps ownership).
 """
 
 from __future__ import annotations
@@ -38,6 +37,7 @@ import os
 from typing import Any, Dict, List, Mapping, Optional, Sequence, Union
 
 from repro.cache import ResultCache
+from repro.core import ShardedGateway
 from repro.core.durable import Journal
 from repro.core.executor import ClusterExecutor, ExecutionReport, LocalExecutor
 from repro.core.gateway import Gateway
@@ -113,7 +113,7 @@ class Client:
         (anything with ``submit``/``start``/``stop``) the caller owns.
     shards:
         With a worker list and ``shards > 1``, build a
-        :class:`~repro.core.aio.ShardedGateway` with that many replicas.
+        :class:`~repro.core.ShardedGateway` with that many replicas.
     workflows:
         The :class:`WorkflowRegistry` naming graph factories for
         :meth:`workflow`; an empty registry is created when omitted so
@@ -294,8 +294,8 @@ class Client:
 
         The cache collector is bound at construction; the gateway collector
         on first gateway use. ``metrics().snapshot()`` /
-        ``metrics().to_prometheus()`` then report identical shapes under
-        both ``REPRO_RUNTIME`` control planes.
+        ``metrics().to_prometheus()`` then report identical shapes for a
+        single gateway and a sharded one.
         """
         return _global_metrics()
 
@@ -332,8 +332,6 @@ class Client:
         """The live gateway (started on first use); None for local clients."""
         if self._gateway is None and self._workers is not None:
             if self.shards > 1:
-                from repro.core.aio import ShardedGateway
-
                 self._gateway = ShardedGateway(
                     self._workers, shards=self.shards, **self._gateway_options
                 )

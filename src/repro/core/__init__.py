@@ -3,7 +3,6 @@
 The paper's contribution, realized for JAX/TPU clusters. See DESIGN.md §2-3.
 """
 
-from .aio import AsyncGateway, AsyncWorkerClient, AsyncWorkerServer, ShardedGateway
 from .context import EMPTY_CONTEXT, Context, ContextEntry, canonical_digest
 from .durable import (
     KNOWN_KINDS,
@@ -31,7 +30,7 @@ from .gateway import (
     round_robin,
 )
 from .graph import ContextGraph, CycleError, Node, UnionNode, toposort_levels
-from .heartbeat import HeartbeatServer, check_heartbeat, check_heartbeat_async, telemetry
+from .heartbeat import HeartbeatServer, check_heartbeat, telemetry
 from .server import (
     FlakyWorker,
     InProcWorker,
@@ -40,6 +39,7 @@ from .server import (
     WorkerServer,
     WorkerStreamError,
 )
+from .shards import ShardedGateway
 
 __all__ = [
     "Context",
@@ -81,11 +81,7 @@ __all__ = [
     "toposort_levels",
     "HeartbeatServer",
     "check_heartbeat",
-    "check_heartbeat_async",
     "telemetry",
-    "AsyncGateway",
-    "AsyncWorkerClient",
-    "AsyncWorkerServer",
     "ShardedGateway",
     "TaskRegistry",
     "WorkerServer",

@@ -16,7 +16,6 @@ from __future__ import annotations
 
 import heapq
 import itertools
-import os
 import random
 import threading
 import time
@@ -164,23 +163,11 @@ _ALGOS: Dict[str, Callable] = {
 class Gateway:
     """Central task router with queue/queue-silo + allocation fallback chain.
 
-    Two runtimes share this class's semantics: the default thread-per-request
-    runtime implemented here, and the asyncio runtime in
-    :mod:`repro.core.aio` (an event-loop pump on a dedicated thread behind
-    the same blocking API). Setting ``REPRO_RUNTIME=async`` makes plain
-    ``Gateway(...)`` construction transparently build the async subclass, so
-    existing callers and tests exercise either runtime unmodified.
+    A heartbeat thread refreshes cached worker telemetry and a fixed pool of
+    dispatch threads drains the queue; callers get one ``Future`` per
+    request. :class:`~repro.core.shards.ShardedGateway` runs N of these as
+    replicas for a control plane that survives a gateway crash.
     """
-
-    def __new__(cls, *args, **kw):
-        """Dispatch to the asyncio runtime when ``REPRO_RUNTIME=async``."""
-        if cls is Gateway and os.environ.get("REPRO_RUNTIME", "").lower() == "async":
-            from .aio.gateway import AsyncGateway
-
-            gw = AsyncGateway(*args, **kw)
-            gw.__dispatched_init__ = True  # __init__ below must not run twice
-            return gw
-        return super().__new__(cls)
 
     def __init__(
         self,
@@ -194,8 +181,6 @@ class Gateway:
         quarantine_s: float = 2.0,
         name: str = "gateway",
     ):
-        if getattr(self, "__dispatched_init__", False):
-            return  # __new__ already ran the async subclass's full __init__
         self.name = name
         self.handles: List[WorkerHandle] = [
             WorkerHandle(worker=w, name=getattr(w, "name", f"w{i}"))
@@ -261,9 +246,10 @@ class Gateway:
 
         Unlike :meth:`stop` this is fault injection, not shutdown — queued
         requests stay unresolved and in-flight futures are left dangling,
-        exactly as if the gateway process died. A :class:`~repro.core.aio.
-        shards.ShardedGateway` detects the ``crashed`` flag and hands the
-        replica's partition to a survivor via the shared journal.
+        exactly as if the gateway process died. A
+        :class:`~repro.core.shards.ShardedGateway` detects the ``crashed``
+        flag and hands the replica's partition to a survivor via the shared
+        journal.
         """
         self.crashed = True
         self.stop()
@@ -527,7 +513,7 @@ class Gateway:
     def _on_invoke_error(
         self, handle: WorkerHandle, req: TaskRequest, exc: BaseException
     ) -> None:
-        """Shared failure taxonomy for a worker invocation (both runtimes).
+        """Failure taxonomy for a worker invocation that raised.
 
         ``ConnectionError`` is a system-level failure: mark dead, requeue
         elsewhere. Siblings still executing on the handle are NOT evicted
@@ -578,7 +564,7 @@ class Gateway:
     def _on_result(
         self, handle: WorkerHandle, req: TaskRequest, result: Mapping[str, Any], dt: float
     ) -> None:
-        """Shared status-dict handling for a completed invocation (both runtimes)."""
+        """Status-dict handling for a completed invocation."""
         owned = self._release(handle, req)
         handle.completed += 1
         handle.ewma_latency_s = (
@@ -621,12 +607,11 @@ class Gateway:
                 self._resubmit(req, f"application error on {handle.name}")
 
     def _apply_probe(self, h: WorkerHandle, tel: Optional[Dict[str, Any]]) -> None:
-        """Apply one heartbeat verdict to a handle (both runtimes).
+        """Apply one heartbeat verdict to a handle.
 
         Liveness transition, telemetry/last_seen/miss bookkeeping, app-level
         self-heal, the once-per-death ``on_worker_down`` edge, and the
-        consecutive-miss eviction threshold all live here so the asyncio
-        prober shares the exact state machine of the threaded one.
+        consecutive-miss eviction threshold.
         """
         with self._track_lock:  # transition must be atomic vs _run_on's
             was_live, h.live = h.live, tel is not None

@@ -14,18 +14,16 @@ Two transports are provided:
 
 from __future__ import annotations
 
-import asyncio
 import json
 import os
 import shutil
 import threading
 import time
-import urllib.parse
 import urllib.request
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Dict, Optional
 
-__all__ = ["telemetry", "HeartbeatServer", "check_heartbeat", "check_heartbeat_async"]
+__all__ = ["telemetry", "HeartbeatServer", "check_heartbeat"]
 
 _START = time.monotonic()  # uptime is interval math: immune to clock steps
 
@@ -172,39 +170,3 @@ def check_heartbeat(address: str, timeout: float = 1.0) -> Optional[Dict[str, An
     except Exception:
         return None
 
-
-async def check_heartbeat_async(
-    address: str, timeout: float = 1.0
-) -> Optional[Dict[str, Any]]:
-    """Coroutine twin of :func:`check_heartbeat` for the asyncio gateway.
-
-    The async control plane probes every worker *concurrently* (one
-    ``gather`` per heartbeat tick instead of a serial walk), so a single
-    slow or dead worker no longer stretches the whole probe cycle. Same
-    contract: None ⇒ system-level failure, a successful report is stamped
-    with a monotonic ``probe_latency_s``.
-    """
-    t0 = time.monotonic()
-    try:
-        parts = urllib.parse.urlsplit(address)
-        host, port = parts.hostname or "127.0.0.1", parts.port or 80
-        reader, writer = await asyncio.wait_for(
-            asyncio.open_connection(host, port), timeout=timeout
-        )
-        try:
-            writer.write(
-                f"GET /heartbeat HTTP/1.1\r\nHost: {host}\r\n"
-                "Connection: close\r\n\r\n".encode()
-            )
-            await writer.drain()
-            raw = await asyncio.wait_for(reader.read(-1), timeout=timeout)
-        finally:
-            writer.close()
-        head, _, body = raw.partition(b"\r\n\r\n")
-        if not head.split(None, 2)[1].startswith(b"200"):
-            return None
-        report = json.loads(body)
-        report["probe_latency_s"] = time.monotonic() - t0
-        return report
-    except Exception:
-        return None

@@ -15,12 +15,6 @@ its chunks cross the wire incrementally as crc-checked frames in a chunked
 response body (docs/streaming.md §5); in-process the generator itself is
 handed to the caller. Either way the consumer sees chunks as they are
 produced, never a materialized batch.
-
-This module is also the *semantic* layer of the asyncio worker transport:
-``repro.core.aio.server`` rebuilds only the HTTP plumbing on an event loop
-and reuses ``_execute`` (middleware chain, DI, failure taxonomy, state
-accounting) and ``_stream_values`` (frame decode + torn-stream detection)
-from here — one execution contract, two transports.
 """
 
 from __future__ import annotations
@@ -113,7 +107,7 @@ def _execute(
     fail_injector: Optional[Callable[[str], None]] = None,
 ) -> Dict[str, Any]:
     # the one worker-side execution contract, shared by every transport
-    # (in-proc, threaded HTTP, asyncio) — which is also why the task span
+    # (in-proc and HTTP) — which is also why the task span
     # is opened here and nowhere transport-specific. Parent identity rides
     # the submitted context as an obs.* fact (see repro.obs.trace).
     tracer = get_tracer()

@@ -35,9 +35,9 @@ from typing import Any, Callable, Dict, List, Mapping, Optional
 from repro.obs.trace import get_tracer
 from repro.wire import payload_digest
 
-from ..context import Context, EMPTY_CONTEXT
-from ..durable import Journal, JournalRecord, ReplayCache
-from ..gateway import AllocationError, Gateway, TaskRequest, WorkerHandle
+from .context import Context, EMPTY_CONTEXT
+from .durable import Journal, JournalRecord, ReplayCache
+from .gateway import AllocationError, Gateway, TaskRequest, WorkerHandle
 
 __all__ = ["ShardedGateway"]
 
@@ -97,11 +97,9 @@ class ShardedGateway:
     """N gateway replicas behind one Gateway-shaped surface.
 
     Construction kwargs beyond ``shards``/``journal`` are forwarded to each
-    replica's ``Gateway(...)`` constructor, which honours ``REPRO_RUNTIME``
-    — so a sharded control plane runs threaded or asyncio replicas with the
-    same code. The executor-facing surface (``submit`` / ``cancel_run`` /
-    ``mark_suspended`` / ``on_requeue``) matches :class:`Gateway` so the
-    ClusterExecutor drives shards unmodified.
+    replica's ``Gateway(...)`` constructor. The executor-facing surface
+    (``submit`` / ``cancel_run`` / ``mark_suspended`` / ``on_requeue``)
+    matches :class:`Gateway` so the ClusterExecutor drives shards unmodified.
     """
 
     def __init__(
@@ -369,16 +367,50 @@ class ShardedGateway:
         return self.replicas[alive[0]].live_workers()
 
     def stats(self) -> Dict[str, Any]:
-        """Merged control-plane snapshot: ring state + per-replica stats."""
+        """Gateway-shaped snapshot merged over alive replicas, plus ring state.
+
+        Depths, dispatch metrics and per-worker counts are summed across the
+        alive replicas; a worker is live if any replica sees it live, and
+        its gauges (misses, latencies, ages) take the worst replica's view.
+        ``metrics`` also carries the ring's handoff counters.
+        """
         with self._lock:
             alive = sorted(self._alive)
             pending = {
                 self.replicas[i].name: len(m) for i, m in self._pending.items()
             }
+        replicas = {self.replicas[i].name: self.replicas[i].stats() for i in alive}
+        metrics: Dict[str, Any] = dict(self.metrics)
+        workers: Dict[str, Dict[str, Any]] = {}
+        suspended: Dict[str, Any] = {}
+        for snap in replicas.values():
+            for key, val in snap["metrics"].items():
+                metrics[key] = metrics.get(key, 0) + val
+            suspended.update(snap["suspended_runs"])
+            for wname, w in snap["workers"].items():
+                merged = workers.get(wname)
+                if merged is None:
+                    workers[wname] = dict(w)
+                    continue
+                for key, val in w.items():
+                    if key in ("live", "app_live"):
+                        merged[key] = merged[key] or val
+                    elif key in ("inflight", "completed"):
+                        merged[key] += val
+                    else:
+                        merged[key] = max(merged[key], val)
         return {
+            "workers": workers,
+            "queue_depth": sum(s["queue_depth"] for s in replicas.values()),
+            "silo_depth": sum(s["silo_depth"] for s in replicas.values()),
+            "suspended_runs": suspended,
+            "live_workers": sum(1 for w in workers.values() if w["live"] and w["app_live"]),
+            "metrics": metrics,
+            "mean_alloc_us": metrics.get("alloc_ns_total", 0)
+            / max(1, metrics.get("alloc_calls", 0))
+            / 1e3,
             "shards": len(self.replicas),
             "alive": [self.replicas[i].name for i in alive],
             "pending": pending,
-            "metrics": dict(self.metrics),
-            "replicas": {self.replicas[i].name: self.replicas[i].stats() for i in alive},
+            "replicas": replicas,
         }

@@ -5,10 +5,9 @@ same context machinery into the observability substrate:
 
 - :mod:`repro.obs.trace` — ``Span``/``Tracer``. Trace identity rides the
   run's Ψ context as a reserved ``obs.``-prefixed fact, so spans nest
-  correctly across the gateway→worker hop on both transports (threaded
-  HTTP and asyncio) and across ``ShardedGateway`` handoffs, with zero
-  transport changes. Off by default; a disabled tracer is one attribute
-  read per call site.
+  correctly across the gateway→worker hop (in-process and HTTP) and
+  across ``ShardedGateway`` handoffs, with zero transport changes. Off by
+  default; a disabled tracer is one attribute read per call site.
 - :mod:`repro.obs.metrics` — ``MetricsRegistry`` with counters, gauges
   and histograms plus pull-collectors that absorb the pre-existing
   ad-hoc stats surfaces (``Gateway.stats()``, ``Channel.stats``,

@@ -14,10 +14,9 @@ Two complementary surfaces feed one snapshot:
 
 Naming scheme (docs/observability.md): ``repro_<subsystem>_<what>[_total]``
 with Prometheus-style ``{label="value"}`` suffixes baked into the name.
-Snapshots are plain dicts, identical under ``REPRO_RUNTIME=thread|async``;
-:meth:`MetricsRegistry.to_prometheus` renders text exposition format and
-:meth:`MetricsRegistry.to_json` a stable JSON document. All timing helpers
-use the monotonic clock.
+Snapshots are plain dicts; :meth:`MetricsRegistry.to_prometheus` renders
+text exposition format and :meth:`MetricsRegistry.to_json` a stable JSON
+document. All timing helpers use the monotonic clock.
 """
 
 from __future__ import annotations
@@ -282,12 +281,11 @@ def _split_labels(key: str) -> Tuple[str, str]:
 
 
 def gateway_collector(gateway: Any) -> Callable[[], Dict[str, float]]:
-    """Adapter over ``Gateway.stats()`` / ``AsyncGateway.stats()``.
+    """Adapter over ``Gateway.stats()`` / ``ShardedGateway.stats()``.
 
     Emits gateway-level gauges, the cumulative ``metrics`` dict as
     ``repro_gateway_<key>_total`` counters, and per-worker gauges labeled
-    ``{worker="..."}`` — schema-identical across both runtimes because
-    ``stats()`` itself is defined once on the base Gateway.
+    ``{worker="..."}``.
     """
 
     def collect() -> Dict[str, float]:

@@ -4,8 +4,8 @@ The trace contract (docs/observability.md) in three invariants:
 
 1. **Propagation is the context.** A span crossing a process boundary is
    carried as one reserved fact under :data:`TRACE_KEY` inside the same
-   ``Context`` that already travels in every task submission — both worker
-   transports (threaded HTTP and asyncio) and ``ShardedGateway`` handoffs
+   ``Context`` that already travels in every task submission — the worker
+   transports (in-process and HTTP) and ``ShardedGateway`` handoffs
    forward it untouched, so no wire format changes.
 2. **Tracing never changes replay identity.** ``obs.``-prefixed facts are
    excluded from ``Context.digest()`` and injected with lamport 0, so a

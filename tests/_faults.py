@@ -160,7 +160,7 @@ class FaultInjector:
         The submission itself lands (queued or in-flight on the replica),
         then :meth:`Gateway.crash` fires — the fault-injection death that
         abandons queued work without draining. With a
-        :class:`~repro.core.aio.ShardedGateway` replica this is the handoff
+        :class:`~repro.core.shards.ShardedGateway` replica this is the handoff
         trigger: the monitor notices ``crashed`` and a survivor adopts the
         dead replica's partition. Restored on teardown.
         """

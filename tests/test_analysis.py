@@ -11,7 +11,7 @@ Covers docs/static-analysis.md:
     ``KNOWN_KINDS`` must be reported at ALL FOUR switch sites
     (replay/compact/lineage/timeline) — a new journal kind cannot ship
     without every reader handling it;
-  - clock-policy (INV201) and async-blocking (INV301/302) detection;
+  - clock-policy (INV201) detection;
   - the ``python -m repro lint`` CLI: ``--json``, baseline write +
     suppression, exit codes, and the self-test that the committed tree is
     clean modulo the committed baseline.
@@ -31,7 +31,6 @@ from repro.analysis import (
     Finding,
     ReplayUnsafeError,
     ReplayUnsafeWarning,
-    check_async_blocking,
     check_callable,
     check_clock_policy,
     check_graph,
@@ -41,7 +40,6 @@ from repro.analysis import (
     split_baselined,
     write_baseline,
 )
-from repro.analysis.cli import lint_paths
 from repro.core import ContextGraph, Gateway, InProcWorker, LocalExecutor, TaskRegistry
 from repro.core import ClusterExecutor
 from repro.core import durable as durable_mod
@@ -338,7 +336,7 @@ def test_stale_kind_at_a_site_is_reported(tmp_path, monkeypatch):
 
 
 # --------------------------------------------------------------------------
-# clock policy + async blocking
+# clock policy
 # --------------------------------------------------------------------------
 
 
@@ -356,30 +354,6 @@ def test_clock_policy_flags_unjustified_and_accepts_justified():
     # monotonic is always policy-clean
     mono = "import time\n\ndef f():\n    return time.monotonic()\n"
     assert check_clock_policy(mono, path="x.py") == []
-
-
-def test_async_blocking_detects_sleep_and_threaded_entry_points():
-    src = (
-        "import time\n"
-        "from repro.core.gateway import Gateway\n"
-        "async def pump():\n"
-        "    time.sleep(1)\n"
-        "    gw = Gateway([])\n"
-        "def sync_ok():\n"
-        "    time.sleep(1)\n"
-    )
-    found = check_async_blocking(src, path="src/repro/core/aio/x.py")
-    assert codes(found) == ["INV301", "INV302"]
-    assert all(f.symbol == "pump" for f in found)
-
-
-def test_aio_package_is_clean_of_blocking_calls():
-    assert lint_paths(
-        [os.path.join(SRC, "repro", "core", "aio")],
-        repo_root=REPO,
-        select=["INV301", "INV302"],
-        kind_checks=False,
-    ) == []
 
 
 # --------------------------------------------------------------------------

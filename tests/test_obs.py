@@ -3,14 +3,14 @@
 The contract under test (docs/observability.md):
   - trace identity rides the run's Ψ context as a digest-excluded,
     lamport-0 ``obs.trace`` fact — injecting it never changes replay
-    identity and it survives the wire roundtrip on both transports,
-  - spans nest across the gateway→worker hop (threaded HTTP *and*
-    asyncio) into one coherent trace, 1:1 with journal NODE_COMMITs,
+    identity and it survives the wire roundtrip,
+  - spans nest across the gateway→worker HTTP hop into one coherent
+    trace, 1:1 with journal NODE_COMMITs,
   - a replica-kill handoff keeps the trace coherent (single trace id,
     no duplicate node spans, a ``handoff`` span audits the adoption),
   - a journal-replay incarnation emits zero duplicate spans,
-  - ``MetricsRegistry`` snapshots are schema-identical across the
-    thread and async runtimes (``Gateway.stats()`` parity),
+  - ``MetricsRegistry`` renders instruments and collectors (gateway,
+    cache, channel) into one snapshot,
   - ``Timeline`` reconstructs per-node timings + critical path from a
     journal, compacted or not.
 """
@@ -22,8 +22,6 @@ import pytest
 from _faults import faults  # noqa: F401 — fixture
 
 from repro.core import (
-    AsyncGateway,
-    AsyncWorkerServer,
     ClusterExecutor,
     Context,
     ContextGraph,
@@ -36,12 +34,7 @@ from repro.core import (
     WorkerClient,
     WorkerServer,
 )
-from repro.obs.metrics import (
-    MetricsRegistry,
-    cache_collector,
-    channel_collector,
-    gateway_collector,
-)
+from repro.obs.metrics import MetricsRegistry, cache_collector, channel_collector
 from repro.obs.sinks import JsonlSink, RingSink, chrome_trace, read_spans
 from repro.obs.timeline import Timeline
 from repro.obs.trace import (
@@ -247,32 +240,6 @@ def test_span_propagation_over_http_worker(tmp_path):
     assert all(sp["attrs"]["queued_s"] >= 0.0 for sp in by_kind["rpc"])
 
 
-def test_span_propagation_over_asyncio_transport(tmp_path):
-    reg = _registry()
-    graph, last = _chain_graph(3)
-    tracer = get_tracer()
-    ring = RingSink()
-    with AsyncWorkerServer("aw0", reg) as server:
-        client = server.client(timeout=5.0)
-        with AsyncGateway([client]) as gw:
-            with Journal(str(tmp_path / "j.wal"), sync="always") as j:
-                with tracer.attached(ring):
-                    rep = ClusterExecutor(gw, journal=j, speculative=False).run(graph)
-                kinds = dict(j.kinds())
-    assert rep.outputs[last] == 2**3
-    spans = ring.spans()
-    assert len({sp["trace"] for sp in spans}) == 1
-    node_spans = [sp for sp in spans if sp["kind"] == "node"]
-    assert len(node_spans) == kinds["NODE_COMMIT"] == len(graph.nodes)
-    rpc = [sp for sp in spans if sp["kind"] == "rpc"]
-    task = [sp for sp in spans if sp["kind"] == "task"]
-    node_ids = {sp["span"] for sp in node_spans}
-    assert rpc and all(sp["parent"] in node_ids for sp in rpc)
-    rpc_ids = {sp["span"] for sp in rpc}
-    assert task and all(sp["parent"] in rpc_ids for sp in task)
-    assert all(sp["attrs"]["queued_s"] >= 0.0 for sp in rpc)
-
-
 def test_replica_kill_handoff_keeps_one_coherent_trace(tmp_path, faults):
     reg = _registry()
     graph, last = _chain_graph(6)
@@ -340,7 +307,7 @@ def test_local_executor_replay_is_span_silent(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# metrics: registry, collectors, runtime parity
+# metrics: registry and collectors
 # ---------------------------------------------------------------------------
 
 
@@ -369,25 +336,6 @@ def test_collector_failure_degrades_to_error_gauge():
     snap = reg.snapshot()
     assert snap["gauges"]["repro_collector_errors"] == 1.0
     assert snap["counters"]["repro_ok_total"] == 2.0  # _total → counter side
-
-
-def test_gateway_stats_parity_across_runtimes():
-    """Satellite: Gateway.stats() and AsyncGateway.stats() expose one schema."""
-    reg = _registry()
-
-    def snapshot_names(gw_cls):
-        with gw_cls([InProcWorker("w0", reg)]) as gw:
-            assert gw.submit("add", inputs={"a": 1, "b": 1}).result(timeout=10) == 2
-            stats = gw.stats()
-            metrics_names = set(gateway_collector(gw)())
-        return set(stats), set(stats["metrics"]), set(stats["workers"]["w0"]), metrics_names
-
-    top_t, met_t, wrk_t, names_t = snapshot_names(Gateway)
-    top_a, met_a, wrk_a, names_a = snapshot_names(AsyncGateway)
-    assert top_t == top_a
-    assert met_t == met_a
-    assert wrk_t == wrk_a
-    assert names_t == names_a  # identical metric names under both runtimes
 
 
 def test_cache_and_channel_collectors(tmp_path):
