@@ -27,15 +27,21 @@ def build_registry(cfg, model, params) -> TaskRegistry:
     decode = jax.jit(model.decode_step)
     tracer = get_tracer()
 
+    def first_token(params, tokens, pad_to):
+        logits, cache = model.prefill(params, {"tokens": tokens}, pad_to=pad_to)
+        return jnp.argmax(logits, axis=-1), cache
+
+    # one program per prompt length (the tokens' shape) and pad_to, lowered
+    # the first time that length is served
+    prefill = jax.jit(first_token, static_argnames="pad_to")
+
     @reg.task("generate")
     def generate(ctx, prompt, new_tokens):
         n = int(new_tokens)
         # serve.prefill: the prompt on the host to the first token on the host
         with tracer.span("serve.prefill", attrs={"prompt_tokens": len(prompt)}):
-            toks = jnp.asarray(np.asarray(prompt, np.int32))[None, :]
-            logits, cache = model.prefill(params, {"tokens": toks},
-                                          pad_to=toks.shape[1] + n)
-            tok = jnp.argmax(logits, axis=-1)
+            toks = np.asarray(prompt, np.int32)[None, :]
+            tok, cache = prefill(params, toks, pad_to=toks.shape[1] + n)
             out = [int(tok[0])] if n else []
         with tracer.span("serve.decode", attrs={"tokens": n}):
             for i in range(n):
