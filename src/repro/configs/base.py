@@ -28,6 +28,8 @@ class ModelConfig:
     # attention flavour
     qkv_bias: bool = False
     qk_norm: bool = False
+    qk_norm_whole: bool = False      # Olmo 2: RMSNorm over the whole q / k projection
+    post_norm: bool = False          # Olmo 2: RMSNorm on each sub-layer's output, no pre-norm
     rope_theta: float = 10_000.0
     rope_fraction: float = 1.0       # stablelm partial rotary
     window: int = 0                  # 0 ⇒ global attention; >0 ⇒ local window
@@ -77,6 +79,14 @@ class ModelConfig:
     mamba_d_head: int = 64
     mamba_d_state: int = 128
     mamba_chunk_size: int = 256
+
+    # Gated DeltaNet mixer (layer kind "gdn"): gdn_n_heads key and value
+    # heads, each conv'd (width conv1d_width, no bias), a gated per-head
+    # RMSNorm on the output; gdn_neg_eigval doubles β (range [0, 2])
+    gdn_n_heads: int = 0
+    gdn_key_dim: int = 128
+    gdn_value_dim: int = 256
+    gdn_neg_eigval: bool = False
 
     # ssm (RWKV6)
     rwkv_head_size: int = 64
@@ -224,4 +234,6 @@ def smoke_variant(cfg: ModelConfig) -> ModelConfig:
     if cfg.mamba_n_heads:
         changes.update(mamba_n_heads=8, mamba_d_head=32, mamba_d_state=16,
                        mamba_chunk_size=16)
+    if cfg.gdn_n_heads:
+        changes.update(gdn_n_heads=4, gdn_key_dim=16, gdn_value_dim=32)
     return replace(cfg, name=cfg.name + "-smoke", **changes)

@@ -20,7 +20,7 @@ from jax.sharding import PartitionSpec as P
 
 from . import ref as _ref
 
-__all__ = ["flash_attention", "wkv6", "rglru", "ssd", "default_impl"]
+__all__ = ["flash_attention", "wkv6", "rglru", "ssd", "gated_delta", "default_impl"]
 
 _IMPLS = ("auto", "pallas", "interpret", "ref", "dense")
 
@@ -209,3 +209,35 @@ def ssd(x, dt, A, B, C, *, chunk: int = 256, initial_state=None,
     bh, b = {"b": 0, "m": 1}, {"b": 0}
     return _per_shard(kernel, (x, dt, A, B, C, h0), (bh, bh, {"m": 0}, b, b, bh),
                       ((4, bh), (4, bh)))
+
+
+# --------------------------------------------------------------------------
+# gated delta rule (Gated DeltaNet)
+# --------------------------------------------------------------------------
+
+def gated_delta(q, k, v, g, beta, *, chunk: int = 64, initial_state=None,
+                impl: str = "auto") -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Linear attention with a gated delta rule, forward only.
+
+    q,k:(Bt,H,T,K) v:(Bt,H,T,V) g,beta:(Bt,H,T) initial_state:(Bt,H,V,K)
+    → (o (Bt,H,T,V), final state (Bt,H,V,K) float32); see
+    ``ref.gated_delta_ref``. q and k come normalised (and q scaled).
+    """
+    impl = _resolve(impl)
+    if impl == "dense":
+        return _ref.gated_delta_ref(q, k, v, g, beta, initial_state=initial_state)
+    if impl == "ref":
+        return _ref.gated_delta_chunked_ref(q, k, v, g, beta, chunk=chunk,
+                                            initial_state=initial_state)
+    from .gated_delta import gated_delta_pallas
+
+    Bt, H, _, K = q.shape
+    h0 = initial_state if initial_state is not None else \
+        jnp.zeros((Bt, H, v.shape[-1], K), jnp.float32)
+
+    def kernel(q, k, v, g, beta, h0):
+        return gated_delta_pallas(q, k, v, g, beta, h0, chunk=chunk,
+                                  interpret=impl == "interpret")
+
+    bh = {"b": 0, "m": 1}
+    return _per_shard(kernel, (q, k, v, g, beta, h0), (bh,) * 6, ((4, bh), (4, bh)))

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 
 __all__ = ["flash_attention_ref", "flash_attention_dense_ref", "wkv6_ref",
            "wkv6_chunked_ref", "rglru_ref", "rglru_scan_ref", "ssd_ref",
-           "ssd_chunked_ref"]
+           "ssd_chunked_ref", "gated_delta_ref", "gated_delta_chunked_ref"]
 
 _NEG_INF = -1e30
 
@@ -328,3 +328,90 @@ def ssd_chunked_ref(x, dt, A, B, C, *, chunk: int = 256, initial_state=None
     h, ys = jax.lax.scan(chunk_step, h0, xs)
     y = jnp.moveaxis(ys, 0, 2).reshape(Bt, H, n * chunk, P)[:, :, :T]
     return y.astype(x.dtype), h
+
+
+# ===========================================================================
+# Gated delta rule: linear attention with a key-dependent erase
+# ===========================================================================
+
+def gated_delta_ref(q, k, v, g, beta, *, initial_state=None
+                    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Sequential oracle (Yang, Kautz & Hatamizadeh, arXiv:2412.06464). Shapes:
+      q, k: (B, H, T, K);  v: (B, H, T, V);  g: (B, H, T) log decay ≤ 0;
+      beta: (B, H, T) in [0, 2];  initial_state: (B, H, V, K).
+    Per head, with S ∈ R^{V×K} and α_t = exp(g_t):
+      S_t = α_t S_{t-1} (I − β_t k_t k_tᵀ) + β_t v_t k_tᵀ;   o_t = S_t q_t
+    Returns (o (B,H,T,V) in v's dtype, final state (B,H,V,K) float32).
+    """
+    Bt, H, T, K = q.shape
+    V = v.shape[-1]
+    qf, kf, vf, gf, bf = (a.astype(jnp.float32) for a in (q, k, v, g, beta))
+    S0 = (jnp.zeros((Bt, H, V, K), jnp.float32) if initial_state is None
+          else initial_state.astype(jnp.float32))
+
+    def step(S, inp):
+        qt, kt, vt, gt, bt = inp     # (Bt,H,K), (Bt,H,K), (Bt,H,V), (Bt,H), (Bt,H)
+        S = jnp.exp(gt)[..., None, None] * S
+        u = bt[..., None] * (vt - jnp.einsum("bhvk,bhk->bhv", S, kt))
+        S = S + u[..., :, None] * kt[..., None, :]
+        return S, jnp.einsum("bhvk,bhk->bhv", S, qt)
+
+    S, os_ = jax.lax.scan(step, S0, tuple(jnp.moveaxis(a, 2, 0) for a in (qf, kf, vf, gf, bf)))
+    return jnp.moveaxis(os_, 0, 2).astype(v.dtype), S
+
+
+def _pad_chunks(q, k, v, g, beta, chunk):
+    """Pad T to a chunk multiple with steps that change nothing: g = 0
+    (no decay), β = 0 and k = 0 (no write); their outputs are sliced off."""
+    pad = (-q.shape[2]) % chunk
+    if pad:
+        q, k, v = (jnp.pad(a, ((0, 0), (0, 0), (0, pad), (0, 0))) for a in (q, k, v))
+        g, beta = (jnp.pad(a, ((0, 0), (0, 0), (0, pad))) for a in (g, beta))
+    return q, k, v, g, beta
+
+
+def gated_delta_chunked_ref(q, k, v, g, beta, *, chunk: int = 64, initial_state=None
+                            ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Chunked WY form (DeltaNet, arXiv:2406.06484), in float32 jnp.
+
+    Per chunk of Q steps from state S₀, with γ the in-chunk prefix sum of g,
+    Γ = exp(γ) and the strictly lower A_tj = β_t exp(γ_t − γ_j) k_t·k_j:
+      U = (I + A)⁻¹ (diag(β) V − diag(βΓ) K S₀ᵀ)      (the written values)
+      O = diag(Γ) Q S₀ᵀ + (M ∘ Q Kᵀ) U,  M_tj = exp(γ_t − γ_j), j ≤ t
+      S_Q = Γ_Q S₀ + Uᵀ diag(exp(γ_Q − γ)) K
+    The triangular system is solved directly here; the kernel inverts it by
+    blocks. The chunk's state is carried to the next in order.
+    """
+    Bt, H, T, K = q.shape
+    V = v.shape[-1]
+    qp, kp, vp, gp, bp = _pad_chunks(
+        *(a.astype(jnp.float32) for a in (q, k, v, g, beta)), chunk)
+    n = qp.shape[2] // chunk
+    S0 = (jnp.zeros((Bt, H, V, K), jnp.float32) if initial_state is None
+          else initial_state.astype(jnp.float32))
+    incl = jnp.tril(jnp.ones((chunk, chunk), bool))
+    strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
+    eye = jnp.eye(chunk, dtype=jnp.float32)
+
+    def chunk_step(S, inp):
+        qc, kc, vc, gc, bc = inp     # (Bt,H,Q,K), (Bt,H,Q,K), (Bt,H,Q,V), (Bt,H,Q) x 2
+        cum = jnp.cumsum(gc, axis=-1)
+        decay = jnp.exp(jnp.where(incl, cum[..., :, None] - cum[..., None, :], -jnp.inf))
+        kk = jnp.einsum("bhik,bhjk->bhij", kc, kc)
+        a = jnp.where(strict, bc[..., :, None] * decay * kk, 0.0)
+        rhs = jnp.concatenate([bc[..., None] * vc,
+                               (bc * jnp.exp(cum))[..., None] * kc], axis=-1)
+        sol = jax.scipy.linalg.solve_triangular(eye + a, rhs, lower=True, unit_diagonal=True)
+        u = sol[..., :V] - jnp.einsum("bhik,bhvk->bhiv", sol[..., V:], S)
+        o = jnp.exp(cum)[..., None] * jnp.einsum("bhik,bhvk->bhiv", qc, S) \
+            + jnp.einsum("bhij,bhjv->bhiv", decay * jnp.einsum("bhik,bhjk->bhij", qc, kc), u)
+        last = cum[..., -1:]
+        S = jnp.exp(last)[..., None] * S \
+            + jnp.einsum("bhjv,bhjk->bhvk", u, jnp.exp(last - cum)[..., None] * kc)
+        return S, o
+
+    xs = tuple(jnp.moveaxis(a.reshape(a.shape[:2] + (n, chunk) + a.shape[3:]), 2, 0)
+               for a in (qp, kp, vp, gp, bp))
+    S, os_ = jax.lax.scan(chunk_step, S0, xs)
+    o = jnp.moveaxis(os_, 0, 2).reshape(Bt, H, n * chunk, V)[:, :, :T]
+    return o.astype(v.dtype), S

@@ -1,4 +1,5 @@
-"""Attention blocks: GQA (qkv-bias, qk-norm, partial RoPE, local window) + MLA.
+"""Attention blocks: GQA (qkv-bias, qk-norm per head or over the whole
+projection, partial RoPE, local window) + MLA.
 
 Two call modes:
   - full-sequence (train / prefill): uses kernels.ops.flash_attention
@@ -38,7 +39,10 @@ def init_gqa(store: ParamStore, name: str, cfg) -> None:
         sub.param("bq", (H * hd,), ("heads",), init="zeros")
         sub.param("bk", (KV * hd,), ("kv_heads",), init="zeros")
         sub.param("bv", (KV * hd,), ("kv_heads",), init="zeros")
-    if cfg.qk_norm:
+    if cfg.qk_norm_whole:
+        sub.param("q_norm", (H * hd,), ("heads",), init="ones")
+        sub.param("k_norm", (KV * hd,), ("kv_heads",), init="ones")
+    elif cfg.qk_norm:
         sub.param("q_norm", (hd,), (None,), init="ones")
         sub.param("k_norm", (hd,), (None,), init="ones")
 
@@ -56,10 +60,13 @@ def _project_qkv(x, p, cfg, positions):
     q = dense(x, p["wq"], p.get("bq"))
     k = dense(x, p["wk"], p.get("bk"))
     v = dense(x, p["wv"], p.get("bv"))
+    if cfg.qk_norm_whole:
+        q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
+        k = rmsnorm(k, p["k_norm"], cfg.norm_eps)
     q = q.reshape(B, -1, H, hd)
     k = k.reshape(B, -1, KV, hd)
     v = v.reshape(B, -1, KV, hd)
-    if cfg.qk_norm:
+    if cfg.qk_norm and not cfg.qk_norm_whole:
         q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
         k = rmsnorm(k, p["k_norm"], cfg.norm_eps)
     # (B, H, S, D) layout for the kernel; rope over positions
@@ -88,8 +95,11 @@ def gqa_attention(x: jax.Array, p: Dict[str, Any], cfg, *,
 
     if cross_kv is not None:
         k, v = cross_kv  # (B, Hkv, Ssrc, hd) — precomputed, already roped/plain
-        q = dense(x, p["wq"], p.get("bq")).reshape(B, S, H, hd)
-        if cfg.qk_norm:
+        q = dense(x, p["wq"], p.get("bq"))
+        if cfg.qk_norm_whole:
+            q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
+        q = q.reshape(B, S, H, hd)
+        if cfg.qk_norm and not cfg.qk_norm_whole:
             q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
         q = jnp.moveaxis(q, 1, 2)
         out = ops.flash_attention(q, k, v, causal=False, scale=scale, impl=cfg.attn_impl)

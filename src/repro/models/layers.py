@@ -145,10 +145,10 @@ def dense(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None) -> jax.Arra
     return out
 
 
-def causal_conv1d(x: jax.Array, weight: jax.Array, bias: jax.Array,
+def causal_conv1d(x: jax.Array, weight: jax.Array, bias: Optional[jax.Array],
                   tail: Optional[jax.Array]) -> Tuple[jax.Array, jax.Array]:
-    """Depthwise causal conv. x: (B,T,W); weight: (K,W); tail: the last K-1
-    inputs before x, zeros when None. Returns (y, new_tail)."""
+    """Depthwise causal conv. x: (B,T,W); weight: (K,W); bias: (W,) or None;
+    tail: the last K-1 inputs before x, zeros when None. Returns (y, new_tail)."""
     B, T, W = x.shape
     K = weight.shape[0]
     if tail is None:
@@ -157,7 +157,9 @@ def causal_conv1d(x: jax.Array, weight: jax.Array, bias: jax.Array,
     y = jnp.zeros((B, T, W), jnp.float32)
     for i in range(K):  # K is tiny (4): unrolled taps, no conv primitive needed
         y = y + xp[:, i: i + T, :].astype(jnp.float32) * weight[i].astype(jnp.float32)
-    y = (y + bias.astype(jnp.float32)).astype(x.dtype)
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
+    y = y.astype(x.dtype)
     return y, xp[:, T:, :]
 
 

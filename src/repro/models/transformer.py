@@ -7,6 +7,7 @@ Layer kinds:
   attn  : alias of dense used inside hybrid patterns (local window applies)
   rwkv  : RWKV6 time-mix + channel-mix
   mamba2: Mamba-2 mixer (conv1d + SSD scan, gated RMSNorm) + GLU MLP
+  gdn   : Gated DeltaNet mixer (conv1d + gated delta rule, gated RMSNorm) + GLU MLP
   enc   : bidirectional encoder self-attention + MLP
   xattn : decoder self-attention + cross-attention + MLP (enc-dec)
 
@@ -17,6 +18,9 @@ along the same leading axis and scanned together with the params.
 
 Modes: "train" (no cache), "prefill" (build cache), "decode" (Sq=1, use
 cache). Every apply returns (h, new_cache_or_None, aux_loss).
+
+Sub-layers are pre-norm (h + f(norm(h))); with ``cfg.post_norm`` (Olmo 2)
+the norm moves to the sub-layer's output (h + norm(f(h))), same parameters.
 """
 from __future__ import annotations
 
@@ -27,6 +31,7 @@ import jax.numpy as jnp
 
 from .attention import (gqa_attention, init_gqa, init_gqa_cache, init_mla,
                         init_mla_cache, mla_attention)
+from .gated_delta import gdn_mixer, init_gdn, init_gdn_state
 from .layers import (ParamStore, apply_norm, dense, glu_mlp, init_glu_mlp,
                      norm_param, shard_activation)
 from .mamba2 import init_mamba2, init_mamba2_state, mamba2_mixer
@@ -93,12 +98,14 @@ def init_layer(store: ParamStore, cfg, kind: str) -> None:
         norm_param(store, "ln2", cfg.d_model, cfg.norm)
         init_rwkv_layer(store, "rwkv", cfg)
         return
-    if kind in ("rec", "mamba2"):
+    if kind in ("rec", "mamba2", "gdn"):
         norm_param(store, "ln1", cfg.d_model, cfg.norm)
         if kind == "rec":
             init_recurrent_block(store, "rec", cfg)
-        else:
+        elif kind == "mamba2":
             init_mamba2(store, "mamba", cfg)
+        else:
+            init_gdn(store, "gdn", cfg)
         norm_param(store, "ln2", cfg.d_model, cfg.norm)
         init_glu_mlp(store, "mlp", cfg.d_model, cfg.d_ff, cfg.glu)
         return
@@ -126,6 +133,8 @@ def init_layer_cache(cfg, kind: str, batch: int, seq_len: int, dtype,
         return init_rglru_state(cfg, batch, dtype)
     if kind == "mamba2":
         return init_mamba2_state(cfg, batch, dtype)
+    if kind == "gdn":
+        return init_gdn_state(cfg, batch, dtype)
     size = min(cfg.window, seq_len) if (cfg.window and kind == "attn") else seq_len
     cache = init_mla_cache(cfg, batch, size, dtype) if cfg.mla \
         else init_gqa_cache(cfg, batch, size, dtype)
@@ -183,6 +192,17 @@ def _residual(h: jax.Array, branch: jax.Array, cfg) -> jax.Array:
     return h + branch
 
 
+def _sub_in(h: jax.Array, p: Dict[str, Any], cfg) -> jax.Array:
+    """A sub-layer's input: the normed residual, or the residual itself
+    under ``post_norm``."""
+    return h if cfg.post_norm else apply_norm(h, p, cfg.norm, cfg.norm_eps)
+
+
+def _sub_out(y: jax.Array, p: Dict[str, Any], cfg) -> jax.Array:
+    """A sub-layer's output as added to the residual (normed under ``post_norm``)."""
+    return apply_norm(y, p, cfg.norm, cfg.norm_eps) if cfg.post_norm else y
+
+
 def apply_layer(h: jax.Array, lp: Dict[str, Any], cfg, kind: str, *,
                 positions: jax.Array, mode: str,
                 cache: Optional[Any] = None,
@@ -191,7 +211,7 @@ def apply_layer(h: jax.Array, lp: Dict[str, Any], cfg, kind: str, *,
     aux = jnp.zeros((), jnp.float32)
     B = h.shape[0]
     seq_len = h.shape[1]
-    if mode == "prefill" and cache is None and kind in ("rwkv", "rec", "mamba2"):
+    if mode == "prefill" and cache is None and kind in ("rwkv", "rec", "mamba2", "gdn"):
         cache = init_layer_cache(cfg, kind, B, seq_len, h.dtype)
 
     if kind == "rwkv":
@@ -210,16 +230,19 @@ def apply_layer(h: jax.Array, lp: Dict[str, Any], cfg, kind: str, *,
             st2["cm_prev"] = x2[:, -1, :]
         return h, st2, aux
 
-    if kind in ("rec", "mamba2"):
-        x1 = apply_norm(h, lp["ln1"], cfg.norm, cfg.norm_eps)
+    if kind in ("rec", "mamba2", "gdn"):
+        x1 = _sub_in(h, lp["ln1"], cfg)
         state = cache if mode != "train" else None
         if kind == "rec":
             mix_out, st = recurrent_block(x1, lp["rec"], cfg, state=state)
-        else:
+        elif kind == "mamba2":
             mix_out, st = mamba2_mixer(x1, lp["mamba"], cfg, state=state)
-        h = _residual(h, mix_out, cfg)
-        x2 = apply_norm(h, lp["ln2"], cfg.norm, cfg.norm_eps)
-        h = _residual(h, glu_mlp(x2, lp["mlp"], cfg.act, cfg.glu), cfg)
+        else:
+            mix_out, st = gdn_mixer(x1, lp["gdn"], cfg, state=state)
+        h = _residual(h, _sub_out(mix_out, lp["ln1"], cfg), cfg)
+        x2 = _sub_in(h, lp["ln2"], cfg)
+        h = _residual(h, _sub_out(glu_mlp(x2, lp["mlp"], cfg.act, cfg.glu), lp["ln2"], cfg),
+                      cfg)
         if mode == "train":
             return h, None, aux
         if mode == "prefill" and st is None:
@@ -227,13 +250,13 @@ def apply_layer(h: jax.Array, lp: Dict[str, Any], cfg, kind: str, *,
         return h, st, aux
 
     # attention-bearing kinds ------------------------------------------------
-    x1 = apply_norm(h, lp["ln1"], cfg.norm, cfg.norm_eps)
+    x1 = _sub_in(h, lp["ln1"], cfg)
     attn_out, new_cache = _self_attention(
         x1, lp, cfg, kind, positions=positions,
         cache=(cache["self"] if kind == "xattn" else cache) if cache is not None
         else None,
         mode=mode)
-    h = _residual(h, attn_out, cfg)
+    h = _residual(h, _sub_out(attn_out, lp["ln1"], cfg), cfg)
     if mode == "prefill":
         new_cache = _prefill_cache_from_full(x1, lp, cfg, kind, positions, seq_len)
 
@@ -257,12 +280,12 @@ def apply_layer(h: jax.Array, lp: Dict[str, Any], cfg, kind: str, *,
         elif mode == "decode":
             new_cache = {"self": new_cache, "cross_k": ck, "cross_v": cv}
 
-    x2 = apply_norm(h, lp["ln2"], cfg.norm, cfg.norm_eps)
+    x2 = _sub_in(h, lp["ln2"], cfg)
     if kind == "moe":
         ffn_out, aux = moe_block(x2, lp["moe"], cfg)
     else:
         ffn_out = glu_mlp(x2, lp["mlp"], cfg.act, cfg.glu)
-    h = _residual(h, ffn_out, cfg)
+    h = _residual(h, _sub_out(ffn_out, lp["ln2"], cfg), cfg)
     h = shard_activation(h, "tokens_bsd")
     return h, (new_cache if mode != "train" else None), aux
 

@@ -229,7 +229,7 @@ class ShardingRules:
             elif key in ("cross_k", "cross_v"):  # (L, B, KV, Ssrc, hd)
                 spec = P(None, self.dp, m if self.cache_on_heads else None,
                          None, None)
-            elif key in ("wkv", "ssm"):    # (L, B, H, K, V) / (L, B, H, P, N)
+            elif key in ("wkv", "ssm", "delta"):  # (L, B, H, ·, ·): per-head states
                 spec = P(None, self.dp, m, None, None)
             elif key in ("h",):            # (L, B, W)
                 spec = P(None, self.dp, m)

@@ -35,8 +35,16 @@ def _smoke(arch):
     return replace(smoke_variant(get_config(arch)), attn_impl="interpret")
 
 
+def _gdn():
+    """Gated DeltaNet layers beside NoPE attention in Olmo blocks."""
+    return _dense(num_layers=3, block_pattern=("gdn", "gdn", "attn"), rope_fraction=0.0,
+                  qk_norm=False, qk_norm_whole=True, post_norm=True, gdn_n_heads=4,
+                  gdn_key_dim=16, gdn_value_dim=32, gdn_neg_eigval=True)
+
+
 CONFIGS = {
     "dense": _dense,
+    "gdn": _gdn,
     "ring": lambda: _windowed(8),  # a ring window shorter than the prompt
     "mamba2": lambda: _smoke("granite-4.0-h-micro"),
     "moe": lambda: _smoke("granite-moe-3b-a800m"),
@@ -90,7 +98,7 @@ def _serve(reg, lengths, vocab, n=4):
 
 @pytest.mark.parametrize("name,length", [("dense", 1), ("dense", 13), ("dense", 21),
                                          ("ring", 21), ("mamba2", 13), ("moe", 13),
-                                         ("rwkv", 13)])
+                                         ("rwkv", 13), ("gdn", 13), ("gdn", 21)])
 def test_generate_matches_eager_prefill(name, length):
     cfg, model, params = _model(name)
     prompt = _prompt(length, cfg.vocab_size, seed=length)

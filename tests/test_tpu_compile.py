@@ -178,6 +178,24 @@ def test_ssd_compiles_at_granite_width(chip):
     _assert_kernel(compiled)
 
 
+def test_gated_delta_compiles_at_olmo_hybrid_width(chip):
+    """Olmo-Hybrid-7B's delta rule at the serving cell's longest prompt: 30
+    heads, keys of 96 and values of 192, chunks of 64, bf16 operands; the
+    benchmark's matcher reads the call's shape from the kernel's HLO."""
+    Bt, H, T, K, V = 1, 30, 32768, 96, 192
+    bf, f32 = jnp.bfloat16, jnp.float32
+
+    def rule(q, k, v, g, beta, s0):
+        return ops.gated_delta(q, k, v, g, beta, chunk=64, initial_state=s0, impl="pallas")
+
+    compiled = _compile(rule, chip, ((Bt, H, T, K), bf), ((Bt, H, T, K), bf), ((Bt, H, T, V), bf),
+                        ((Bt, H, T), f32), ((Bt, H, T), f32), ((Bt, H, V, K), f32))
+    (call,) = _mosaic_calls(compiled)
+    assert _bench_kernel("gated_delta").call_of(call) == {
+        "batch": Bt, "heads": H, "seq": T, "key_dim": K, "value_dim": V, "chunk": 64,
+        "dtype_bytes": 2}
+
+
 def _demo_step_compiled(params_sharding, batch_sharding):
     """Full-width serpytor-demo-100m step, global batch 8 x 1024, as chip_smoke.py trains it."""
     model = build(get_config("serpytor-demo-100m"))
